@@ -182,7 +182,7 @@ def _paired_times(cfgs, mesh, axes, n_emit, cap, samples, ballast_iters=0,
     fns, x = {}, jnp.arange(8.0)
     for name, cfg in cfgs.items():
         f = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 _emit_kernel(cfg, n_emit, cap, ballast_iters), mesh=mesh,
                 in_specs=P(axes), out_specs=P(axes),
             )

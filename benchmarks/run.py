@@ -196,16 +196,10 @@ def fig8_efficiency():
 
     # AbstractMesh: lower for the 256-chip production mesh without devices
     mesh = compat.abstract_mesh((16, 16), ("data", "model"))
-    if mesh is None:
-        print("# fig8_efficiency skipped: no AbstractMesh in this JAX")
-        return
     R = 256
     item_b = item_nbytes(_ray_proto())
     for n_emit in (64, 512, 4096, 32768):
-        exchanges = ["padded"] + (
-            ["ragged"] if compat.HAS_RAGGED_ALL_TO_ALL else []
-        )
-        for exchange in exchanges:
+        for exchange in ("padded", "ragged"):
             cap = max(n_emit, 256)
             cfg = ForwardConfig(
                 ("data", "model"), R, cap, exchange=exchange,
@@ -214,8 +208,8 @@ def fig8_efficiency():
             kern = _emit_kernel(cfg, n_emit, cap)
             t0 = time.perf_counter()
             low = jax.jit(
-                compat.shard_map(kern, mesh=mesh, in_specs=P(("data", "model")),
-                                 out_specs=P(("data", "model")))
+                jax.shard_map(kern, mesh=mesh, in_specs=P(("data", "model")),
+                              out_specs=P(("data", "model")))
             ).lower(jnp.arange(512.0))
             lower_us = (time.perf_counter() - t0) * 1e6
             coll = collective_bytes(low.as_text())
@@ -269,8 +263,8 @@ def fwd_walltime():
             cfg = ForwardConfig("data", 8, cap, exchange=exchange, **kw)
             record_cfg(f"fwd_walltime_{exchange}_n{n_emit}", cfg, mesh)
             f = jax.jit(
-                compat.shard_map(_emit_kernel(cfg, n_emit, cap), mesh=mesh,
-                                 in_specs=P("data"), out_specs=P("data"))
+                jax.shard_map(_emit_kernel(cfg, n_emit, cap), mesh=mesh,
+                              in_specs=P("data"), out_specs=P("data"))
             )
             us, _ = _timeit(f, jnp.arange(8.0))
             rays_s = 8 * n_emit / (us / 1e6)
@@ -314,7 +308,7 @@ def _hier_pair(nodes, devs, n_emit, cap):
 
 def _time_fwd(cfg, mesh, n_emit, cap, iters=5):
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             _emit_kernel(cfg, n_emit, cap), mesh=mesh,
             in_specs=P(cfg.axis_name), out_specs=P(cfg.axis_name),
         )
@@ -419,7 +413,7 @@ def _time_fwd_axes(cfg, mesh, axes, n_emit, cap, iters=5):
     """Like _time_fwd but with explicit shard_map axes (the config's level
     axes may be nested tuples, which PartitionSpec cannot carry)."""
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             _emit_kernel(cfg, n_emit, cap), mesh=mesh,
             in_specs=P(axes), out_specs=P(axes),
         )
@@ -500,7 +494,7 @@ def rebalance_skew():
             return nq.count[None] + checksum.astype(jnp.int32)
 
         f = jax.jit(
-            compat.shard_map(bal, mesh=mesh, in_specs=P(axes), out_specs=P(axes))
+            jax.shard_map(bal, mesh=mesh, in_specs=P(axes), out_specs=P(axes))
         )
         us, _ = _timeit(f, jnp.arange(8.0))
         per_tier = per_tier_collective_bytes(
@@ -580,7 +574,7 @@ def _drift_run_burst(mesh, axes, num_ranks, cap, n_emit, rounds, times):
                 ),
             )
             compiled[cfg] = jax.jit(
-                compat.shard_map(
+                jax.shard_map(
                     drive, mesh=mesh, in_specs=P(axes),
                     out_specs=(P(axes), ring_spec),
                 )

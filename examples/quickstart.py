@@ -94,7 +94,7 @@ ring_specs = jax.tree.map(
     TM.make_ring(TM.num_tiers(cfg), window=cfg.telemetry_window,
                  buckets=cfg.telemetry_buckets),
 )
-f = jax.jit(compat.shard_map(
+f = jax.jit(jax.shard_map(
     drive, mesh=mesh, in_specs=P("data"),
     out_specs=(P("data"), P("data"), ring_specs),
 ))
@@ -124,7 +124,7 @@ assert summary["drops"] == 0
 #    drive is bit-exact with the bulk round.
 section(5, "pipelined overlap, bit-exact")
 cfg = dataclasses.replace(cfg, pipeline_shards=2)
-f2 = jax.jit(compat.shard_map(
+f2 = jax.jit(jax.shard_map(
     drive, mesh=mesh, in_specs=P("data"),
     out_specs=(P("data"), P("data"), ring_specs),
 ))

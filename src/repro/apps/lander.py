@@ -36,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.apps import fields as F
 from repro.core import (
     DISCARD,
@@ -184,7 +182,7 @@ def render_forwarding(
         q, fb, rounds, _done = run_until_done(round_fn, q0, fb, cfg, max_rounds=max_rounds)
         return jax.lax.psum(fb, AXIS), rounds[None], q.drops[None]
 
-    f = jax.jit(compat.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
+    f = jax.jit(jax.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
                               out_specs=(P(), P(AXIS), P(AXIS))))
     img, rounds, drops = f(jnp.arange(R, dtype=jnp.float32))
     return (
@@ -270,7 +268,7 @@ def render_deep_compositing(
             nfrag = nfrag + fits.astype(jnp.int32)
         return fragL, fragT, fragD, dropped[None]
 
-    f = jax.jit(compat.shard_map(rank_fragments, mesh=mesh, in_specs=P(AXIS),
+    f = jax.jit(jax.shard_map(rank_fragments, mesh=mesh, in_specs=P(AXIS),
                               out_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS))))
     allL, allT, allD, dropped = f(jnp.arange(R, dtype=jnp.float32))
     # host-side composite (the "sort-last" stage): depth-sort, front-to-back

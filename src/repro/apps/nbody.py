@@ -39,7 +39,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
-
 from repro.core import (
     DISCARD,
     ForwardConfig,
@@ -290,11 +289,14 @@ def run(mesh, cfg: NBodyConfig = NBodyConfig()) -> Tuple[np.ndarray, np.ndarray,
             jnp.ones(n, bool),
         )
 
+        def varying(q):  # one carry type whatever each step's queue holds
+            return jax.tree.map(lambda x: compat.pcast_varying(x, (AXIS,)), q)
+
         def body(pq, _):
             new_pq, total = timestep(pq, None)
-            return new_pq, total
+            return varying(new_pq), total
 
-        pq, totals = jax.lax.scan(body, q0, None, length=cfg.steps)
+        pq, totals = jax.lax.scan(body, varying(q0), None, length=cfg.steps)
 
         # merge final state by uid (disjoint ownership — pmin over +inf pad)
         lane = jnp.arange(cap_p)
@@ -314,9 +316,9 @@ def run(mesh, cfg: NBodyConfig = NBodyConfig()) -> Tuple[np.ndarray, np.ndarray,
         return pos, vel, totals, pq.drops[None]
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             drive, mesh=mesh, in_specs=P(AXIS),
-            out_specs=(P(), P(), P(), P(AXIS)), check_vma=False,
+            out_specs=(P(), P(), P(), P(AXIS)),
         )
     )
     pos, vel, totals, drops = f(jnp.arange(R, dtype=jnp.float32))

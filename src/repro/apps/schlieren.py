@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.apps import fields as F
 from repro.core import (
     DISCARD,
@@ -172,7 +170,7 @@ def render(
         q, fb2, rounds, _done = run_until_done(round_fn, q0, fb2, cfg, max_rounds=max_rounds)
         return jax.lax.psum(fb2, AXIS), rounds[None], q.drops[None]
 
-    f = jax.jit(compat.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
+    f = jax.jit(jax.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
                               out_specs=(P(), P(AXIS), P(AXIS))))
     fb2, rounds, drops = f(jnp.arange(R, dtype=jnp.float32))
     fb2 = np.asarray(fb2)

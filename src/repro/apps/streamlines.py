@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.core import (
     DISCARD,
     ForwardConfig,
@@ -144,10 +142,8 @@ def run(
         merged = jax.lax.pmin(jnp.where(jnp.isnan(traces), jnp.inf, traces), AXIS)
         return merged, rounds[None], q.drops[None]
 
-    # check_vma=False: interpret-mode pallas_call inside shard_map cannot
-    # track varying-manual-axes (Mosaic-compiled kernels on real TPU can).
-    f = jax.jit(compat.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
-                              out_specs=(P(), P(AXIS), P(AXIS)), check_vma=False))
+    f = jax.jit(jax.shard_map(drive, mesh=mesh, in_specs=P(AXIS),
+                              out_specs=(P(), P(AXIS), P(AXIS))))
     merged, rounds, drops = f(jnp.arange(R, dtype=jnp.float32))
     traces = np.array(merged)
     traces[~np.isfinite(traces)] = np.nan

@@ -34,14 +34,12 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-
-from repro import compat
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.apps import fields as F
 from repro.core import (
@@ -207,7 +205,7 @@ def _raygen(me, *, part, blobs, key, scene, cap, num_ranks):
     return q0, fb
 
 
-def render(
+def renderer(
     mesh,
     scene: VopatScene = VopatScene(),
     *,
@@ -218,14 +216,12 @@ def render(
     use_pallas: bool = False,
     telemetry: bool = False,
     telemetry_window: int = 32,
-) -> Tuple[np.ndarray, dict]:
-    """Distributed render. Returns (image (H,W) float, stats dict).
+) -> Callable[[], Tuple[np.ndarray, dict]]:
+    """Build the distributed render of ``scene`` on ``mesh`` once.
 
-    With ``telemetry`` the drive loop carries the flight-recorder ring and
-    the stats dict gains a ``"telemetry"`` summary (per-tier demand
-    histogram/max, clamp drops — see ``repro.telemetry.summarize``): the
-    measured basis for replacing this module's worst-case §6.3 queue sizing
-    with ``repro.tune``-planned capacities."""
+    Returns a function of no arguments that renders one frame and returns
+    ``(image (H,W) float, stats dict)``; its first call compiles, later calls
+    reuse the compiled program.  See :func:`render` for the stats."""
     R = mesh.shape[AXIS]
     if blobs is None:
         blobs = F.default_blobs(scene.num_blobs, scene.seed)
@@ -258,16 +254,16 @@ def render(
         if telemetry:
             from repro.telemetry import stats as TS
 
-            q, fb, rounds, _done, ring = run_until_done(
+            q, fb, rounds, done, ring = run_until_done(
                 round_fn, q0, fb, cfg, max_rounds=max_rounds
             )
             img = jax.lax.psum(fb, AXIS)
-            return img, rounds[None], q.drops[None], TS.stack_ring(ring)
-        q, fb, rounds, _done = run_until_done(round_fn, q0, fb, cfg, max_rounds=max_rounds)
+            return img, done, rounds[None], q.drops[None], TS.stack_ring(ring)
+        q, fb, rounds, done = run_until_done(round_fn, q0, fb, cfg, max_rounds=max_rounds)
         img = jax.lax.psum(fb, AXIS)
-        return img, rounds[None], q.drops[None]
+        return img, done, rounds[None], q.drops[None]
 
-    out_specs = (P(), P(AXIS), P(AXIS))
+    out_specs = (P(), P(), P(AXIS), P(AXIS))
     if telemetry:
         from repro.telemetry import stats as TS
 
@@ -277,25 +273,51 @@ def render(
         )
         out_specs = out_specs + (jax.tree.map(lambda _: P(AXIS), ring_proto),)
     f = jax.jit(
-        compat.shard_map(
-            drive, mesh=mesh, in_specs=P(AXIS), out_specs=out_specs,
-            # interpret-mode pallas_call can't track varying-manual-axes
-            check_vma=not use_pallas,
-        )
+        jax.shard_map(drive, mesh=mesh, in_specs=P(AXIS), out_specs=out_specs)
     )
-    out = f(jnp.arange(R, dtype=jnp.float32))
-    img, rounds, drops = out[:3]
-    img = np.asarray(img).reshape(scene.height, scene.width) / scene.spp
-    stats = {
-        "rounds": int(np.max(np.asarray(rounds))),
-        "drops": int(np.sum(np.asarray(drops))),
-        "majorant": mu,
-        "capacity": cap,
-    }
-    if telemetry:
-        from repro import telemetry as TM
+    x = jax.device_put(np.arange(R, dtype=np.float32), NamedSharding(mesh, P(AXIS)))
 
-        stats["telemetry"] = TM.summarize(
-            out[3], tier_capacities=TM.tier_capacities(cfg)
-        )
-    return img, stats
+    def run() -> Tuple[np.ndarray, dict]:
+        out = f(x)
+        img, done, rounds, drops = out[:4]
+        img = np.asarray(img).reshape(scene.height, scene.width) / scene.spp
+        stats = {
+            "done": bool(done),
+            "rounds": int(np.max(np.asarray(rounds))),
+            "drops": int(np.sum(np.asarray(drops))),
+            "majorant": mu,
+            "capacity": cap,
+            # the device holding each rank's shard, in rank order
+            "devices": [s.device for s in sorted(
+                rounds.addressable_shards, key=lambda s: s.index[0].start or 0
+            )],
+        }
+        if telemetry:
+            from repro import telemetry as TM
+
+            stats["telemetry"] = TM.summarize(
+                out[4], tier_capacities=TM.tier_capacities(cfg)
+            )
+        return img, stats
+
+    return run
+
+
+def render(
+    mesh,
+    scene: VopatScene = VopatScene(),
+    **kwargs,
+) -> Tuple[np.ndarray, dict]:
+    """Distributed render. Returns (image (H,W) float, stats dict).
+
+    Keyword arguments are those of :func:`renderer`.  ``stats["done"]`` is
+    the termination verdict: False when ``max_rounds`` cut the frame with
+    rays still in flight, so the image is incomplete.  ``stats["devices"]``
+    lists the device that ran each rank.
+
+    With ``telemetry`` the drive loop carries the flight-recorder ring and
+    the stats dict gains a ``"telemetry"`` summary (per-tier demand
+    histogram/max, clamp drops — see ``repro.telemetry.summarize``): the
+    measured basis for replacing this module's worst-case §6.3 queue sizing
+    with ``repro.tune``-planned capacities."""
+    return renderer(mesh, scene, **kwargs)()

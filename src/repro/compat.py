@@ -1,197 +1,49 @@
-"""Version-gated JAX imports, centralized (one shim, no scattered try/excepts).
+"""The few JAX mesh and typing helpers the repo shares.
 
-The repo targets the newest JAX surface (``jax.shard_map``, explicit
-``AxisType`` meshes, ``jax.typeof(...).vma`` + ``pcast`` for manual-axes
-typing, ``lax.ragged_all_to_all``), but must also run on older releases
-(0.4.x) where none of those exist.  Every feature-probe lives here; the rest
-of the codebase imports *this* module and never touches ``jax.__version__``.
-
-Exported surface:
-
-  AxisType, HAS_AXIS_TYPE     sharding axis types (None / False when absent)
-  make_mesh(...)              ``jax.make_mesh`` that drops ``axis_types`` when
-                              the installed JAX does not accept it
-  abstract_mesh(...)          device-free mesh for lowering-only benchmarks,
-                              papering over the AbstractMesh signature change
-  shard_map(...)              ``jax.shard_map`` when present, else the
-                              ``jax.experimental.shard_map`` fallback; the
-                              ``check_vma`` kwarg maps onto old ``check_rep``
-  HAS_RAGGED_ALL_TO_ALL       feature flag for ``lax.ragged_all_to_all``
-  ragged_all_to_all(...)      the op, or a loud NotImplementedError stub
-  vma_of(x)                   ``jax.typeof(x).vma`` or ``frozenset()``
-  pcast_varying(x, axes)      ``lax.pcast(..., to="varying")`` or identity
-  sds(shape, dtype, *like)    ShapeDtypeStruct carrying the union of the
-                              inputs' varying-manual-axes when supported
-
-Tests that *require* a missing feature should gate on the ``HAS_*`` flags
-with ``pytest.skip`` rather than erroring at import time.
+  make_mesh(...)              ``jax.make_mesh`` with implicit (Auto) axes —
+                              JAX's own default is Explicit axes, which this
+                              repo's sharding rules do not use
+  abstract_mesh(...)          device-free mesh for lowering-only benchmarks
+  pcast_varying(x, axes)      cast ``x`` to device-varying over ``axes``
+                              (a no-op for axes it already varies over)
+  ragged_executes()           whether the default backend can execute
+                              ``lax.ragged_all_to_all`` (XLA:CPU lowers the
+                              op but cannot run it)
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
-__all__ = [
-    "AxisType",
-    "HAS_AXIS_TYPE",
-    "HAS_RAGGED_ALL_TO_ALL",
-    "HAS_SHARD_MAP_VMA",
-    "abstract_mesh",
-    "make_mesh",
-    "pcast_varying",
-    "ragged_all_to_all",
-    "sds",
-    "shard_map",
-    "vma_of",
-]
+__all__ = ["abstract_mesh", "make_mesh", "pcast_varying", "ragged_executes"]
 
-# ---------------------------------------------------------------- AxisType
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPE = True
-except ImportError:
-    AxisType = None
-    HAS_AXIS_TYPE = False
+# backends whose runtime implements ragged_all_to_all
+_RAGGED_BACKENDS = ("tpu", "gpu")
 
 
-# ---------------------------------------------------------------- make_mesh
-@functools.lru_cache(maxsize=1)
-def _make_mesh_takes_axis_types() -> bool:
-    import inspect
-
-    return "axis_types" in inspect.signature(jax.make_mesh).parameters
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *, axis_types=None):
-    """``jax.make_mesh`` with ``axis_types`` applied only where supported.
-
-    ``axis_types=None`` (the default) means implicit Auto axes — on older JAX
-    that is exactly what dropping the argument gives, so the fallback is
-    silent.  EXPLICITLY requested axis_types on a JAX that cannot honor them
-    raise rather than silently changing sharding semantics.
-    """
-    if HAS_AXIS_TYPE and _make_mesh_takes_axis_types():
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(tuple(axis_names))
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    if axis_types is not None:
-        raise TypeError(
-            f"this JAX ({jax.__version__}) cannot honor axis_types={axis_types!r}; "
-            "omit the argument for implicit Auto axes"
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *, axis_types=None, devices=None):
+    """``jax.make_mesh`` whose axes default to ``AxisType.Auto``."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types, devices=devices)
 
 
-def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """A device-free mesh usable for ``.lower()`` (no execution).
-
-    Handles both AbstractMesh signatures: new ``(shapes, names, axis_types=)``
-    and old ``(shape_tuple,)``.  Returns None when AbstractMesh is absent.
-    """
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError:
-        return None
-    if HAS_AXIS_TYPE:
-        try:
-            return AbstractMesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
-            )
-        except TypeError:
-            pass
-    try:
-        return AbstractMesh(tuple(zip(tuple(axis_names), tuple(axis_shapes))))
-    except TypeError:
-        return None
-
-
-# ---------------------------------------------------------------- shard_map
-HAS_SHARD_MAP_VMA = hasattr(jax, "shard_map")
-
-if HAS_SHARD_MAP_VMA:
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    _shard_map_impl = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Uniform shard_map entry point.
-
-    ``check_vma`` maps to the new-style varying-manual-axes check; on legacy
-    JAX the analogous ``check_rep`` is force-disabled — the legacy checker
-    predates several collectives used here (sort, ragged exchange) and
-    rejects valid programs.
-    """
-    if _shard_map_impl is not None:
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    return _legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> AbstractMesh:
+    """A device-free mesh usable for ``.lower()`` (no execution)."""
+    names = tuple(axis_names)
+    return AbstractMesh(
+        tuple(axis_shapes), names, axis_types=(AxisType.Auto,) * len(names)
     )
 
 
-# ------------------------------------------------------- ragged_all_to_all
-HAS_RAGGED_ALL_TO_ALL = hasattr(jax.lax, "ragged_all_to_all")
-
-if HAS_RAGGED_ALL_TO_ALL:
-    ragged_all_to_all = jax.lax.ragged_all_to_all
-else:
-
-    def ragged_all_to_all(*args, **kwargs):
-        raise NotImplementedError(
-            "jax.lax.ragged_all_to_all is not available in this JAX "
-            f"({jax.__version__}); use the 'padded' exchange backend or "
-            "upgrade JAX"
-        )
-
-
-# --------------------------------------------------- manual-axes vma typing
-_HAS_TYPEOF = hasattr(jax, "typeof")
-_HAS_PCAST = hasattr(jax.lax, "pcast")
-
-
-def vma_of(x) -> frozenset:
-    """The varying-manual-axes set of ``x`` (empty set when untyped JAX)."""
-    if _HAS_TYPEOF:
-        try:
-            return frozenset(jax.typeof(x).vma)
-        except (AttributeError, TypeError):
-            pass
-    return frozenset()
-
-
 def pcast_varying(x, axes):
-    """Cast ``x`` to device-varying over ``axes`` where the type system
-    exists; identity elsewhere (legacy shard_map carries no vma types)."""
-    if not (_HAS_TYPEOF and _HAS_PCAST):
-        return x
-    missing = tuple(a for a in axes if a not in vma_of(x))
+    """Cast ``x`` to device-varying over ``axes`` it does not vary over yet."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
     return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
-@functools.lru_cache(maxsize=1)
-def _sds_accepts_vma() -> bool:
-    try:
-        jax.ShapeDtypeStruct((1,), "int32", vma=frozenset())
-        return True
-    except TypeError:
-        return False
-
-
-def sds(shape, dtype, *like: Any) -> jax.ShapeDtypeStruct:
-    """ShapeDtypeStruct whose vma is the union of the inputs' — required so
-    pallas_call composes with shard_map(check_vma=True).  Plain struct on
-    JAX versions without vma typing."""
-    if _sds_accepts_vma():
-        vma = frozenset()
-        for x in like:
-            vma = vma | vma_of(x)
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+def ragged_executes() -> bool:
+    """True when JAX's default backend can run ``lax.ragged_all_to_all``."""
+    return jax.default_backend() in _RAGGED_BACKENDS

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import queue as Q
 from repro.core import termination as term
 from repro.core.forwarding import ForwardConfig, flatten_axis_names, forward_work
@@ -138,7 +137,7 @@ class RafiContext:
     def shard(self, fn: Callable, *, in_specs, out_specs) -> Callable:
         """shard_map + jit a per-rank function over the context's mesh."""
         return jax.jit(
-            compat.shard_map(fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs)
+            jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs)
         )
 
     def forward_rays(self) -> Callable:

@@ -167,7 +167,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import stages as ST
 from repro.telemetry import stats as TS
 
@@ -709,7 +708,7 @@ def exchange_ragged(
         sorted_packed = jnp.take(packed, perm, axis=0)  # the ONE payload permute
     out = jnp.zeros((capacity, packed.shape[1]), packed.dtype)
     if pipeline_shards == 1:
-        out = compat.ragged_all_to_all(  # the ONE payload collective
+        out = jax.lax.ragged_all_to_all(  # the ONE payload collective
             sorted_packed,
             out,
             input_offsets=off,
@@ -727,7 +726,7 @@ def exchange_ragged(
                 s_ss, s_oo, s_rs = ST.ragged_control_plane(cnt_k, me, capacity)
             else:
                 s_ss, s_oo, s_rs = send_sizes, output_offsets, recv_sizes
-            out = compat.ragged_all_to_all(  # shard k's payload collective
+            out = jax.lax.ragged_all_to_all(  # shard k's payload collective
                 sorted_packed,
                 out,
                 input_offsets=off + jnp.minimum(k * chunk, s_ss),

@@ -744,10 +744,8 @@ class CountExchange:
             if st.flow == "credit":
                 # widen (R, 1) → (R, 2): column 1 carries my receive room to
                 # every peer; received column 1 is all R advertisements
-                wide = jnp.stack(
-                    [st.clamped,
-                     jnp.full_like(st.clamped, st.my_free)], axis=1
-                )
+                advert = jnp.broadcast_to(st.my_free, st.clamped.shape)
+                wide = jnp.stack([st.clamped, advert.astype(st.clamped.dtype)], axis=1)
                 recv = a2a(wide, self.axis_name)
                 st.recv_counts = recv[:, 0]
                 st.credits_out = recv[:, 1]

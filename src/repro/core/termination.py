@@ -88,7 +88,7 @@ def _split_retained(q: WorkQueue) -> Tuple[jax.Array, WorkQueue]:
 
 def _merge_retained(
     q: WorkQueue, n_ret: jax.Array, out_q: WorkQueue, age: jax.Array,
-    limit=None,
+    axis_name, limit=None,
 ) -> Tuple[WorkQueue, jax.Array]:
     """Recombine the retained front of ``q`` with ``round_fn``'s output queue
     (retained FIRST — FIFO priority through the stable marshal).  Emissions
@@ -100,7 +100,12 @@ def _merge_retained(
     arrivals, which is what makes the credit law receiver-drop-free even
     against an app that ignores its emission headroom.  Retained rows are
     never cut — ``limit`` binds emissions only.
-    Returns ``(merged_queue, age_in)`` ready for ``forward_work``."""
+    Returns ``(merged_queue, age_in)`` ready for ``forward_work``.
+
+    The merge is one payload pass, and none when nothing is retained: a
+    ``lax.cond`` picks the pass.  Both branches' outputs are cast to varying
+    over ``axis_name`` so their manual-axes types agree whatever the app's
+    queue carries."""
     C = q.capacity
     lane = jnp.arange(C, dtype=jnp.int32)
     tail = jnp.clip(lane - n_ret, 0, C - 1)
@@ -122,13 +127,14 @@ def _merge_retained(
             jnp.where(valid_tail, jnp.take(out_q.dest, tail), DISCARD),
         ).astype(jnp.int32)
         age_in = jnp.where(front, age, 0).astype(jnp.int32)
-        return items, dest, age_in
+        return _vary((items, dest, age_in), axis_name)
 
     def passthrough(_):
         # nothing retained: the merge is out_q verbatim (lanes past count
         # masked to DISCARD, matching the shifted-merge output bit for bit)
         dest = jnp.where(lane < out_q.count, out_q.dest, DISCARD)
-        return out_q.items, dest.astype(jnp.int32), jnp.zeros((C,), jnp.int32)
+        zeros = jnp.zeros((C,), jnp.int32)
+        return _vary((out_q.items, dest.astype(jnp.int32), zeros), axis_name)
 
     items, dest, age_in = jax.lax.cond(n_ret > 0, merge, passthrough, None)
     merged = WorkQueue(
@@ -288,7 +294,9 @@ def drive_segment(
             elif wants_headroom:
                 kw["headroom"] = jnp.maximum(cfg.capacity - n_ret, 0)
             out_q, aux = round_fn(view, aux, rnd, **kw)
-            fwd_q, age_in = _merge_retained(q, n_ret, out_q, c["age"], limit)
+            fwd_q, age_in = _merge_retained(
+                q, n_ret, out_q, c["age"], cfg.axis_name, limit
+            )
             attempted = out_q.count + out_q.drops
         else:
             consumed = q.count

@@ -22,7 +22,6 @@ def rank_and_histogram(
     count: jax.Array,
     *,
     num_ranks: int,
-    tile: int = 2048,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Pallas-path equivalent of ``core.sorting.destination_rank``:
@@ -30,13 +29,8 @@ def rank_and_histogram(
     vector."""
     if interpret is None:
         interpret = default_interpret()
-    cap = dest.shape[0]
-    # pick a tile that divides the capacity
-    t = min(tile, cap)
-    while cap % t:
-        t //= 2
     return K.rank_and_histogram(
-        dest, count, num_ranks=num_ranks, tile=t, interpret=interpret
+        dest, count, num_ranks=num_ranks, interpret=interpret
     )
 
 

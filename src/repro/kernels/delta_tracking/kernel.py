@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import sds
+from repro.kernels import call, sds
 
 STILL, HIT, EXITED = 0, 1, 2
 
@@ -80,24 +80,28 @@ def track(
     tile = min(tile, n)
     while n % tile:
         tile //= 2
-    return pl.pallas_call(
-        functools.partial(_track_kernel, majorant=majorant, steps=steps),
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 3), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 3), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile, uniforms.shape[1], 2), lambda i: (i, 0, 0)),
-            pl.BlockSpec((g, 5), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            sds((n,), jnp.float32, origins, dirs, t0, uniforms),
-            sds((n,), jnp.int32, origins, dirs, t0, uniforms),
-        ],
-        interpret=interpret,
-    )(origins, dirs, t0, t_exit, uniforms, blobs)
+
+    def kernel(origins, dirs, t0, t_exit, uniforms, blobs):
+        return pl.pallas_call(
+            functools.partial(_track_kernel, majorant=majorant, steps=steps),
+            grid=(n // tile,),
+            in_specs=[
+                pl.BlockSpec((tile, 3), lambda i: (i, 0)),
+                pl.BlockSpec((tile, 3), lambda i: (i, 0)),
+                pl.BlockSpec((tile,), lambda i: (i,)),
+                pl.BlockSpec((tile,), lambda i: (i,)),
+                pl.BlockSpec((tile, uniforms.shape[1], 2), lambda i: (i, 0, 0)),
+                pl.BlockSpec((g, 5), lambda i: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((tile,), lambda i: (i,)),
+                pl.BlockSpec((tile,), lambda i: (i,)),
+            ],
+            out_shape=[
+                sds((n,), jnp.float32, origins, dirs, t0, uniforms),
+                sds((n,), jnp.int32, origins, dirs, t0, uniforms),
+            ],
+            interpret=interpret,
+        )(origins, dirs, t0, t_exit, uniforms, blobs)
+
+    return call(kernel, origins, dirs, t0, t_exit, uniforms, blobs, interpret=interpret)

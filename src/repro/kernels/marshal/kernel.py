@@ -3,30 +3,29 @@
 ``gather_rows`` — the production hot path, the SINGLE-pass marshal: the
 caller composes the destination-sort permutation with the padded send layout
 (``src[i] = perm[off[r] + s]``) and this kernel materialises
-``out[i] = packed[src[i]]`` in one gather.  The index vector lands in SMEM by
-scalar prefetch; each grid step copies one dynamically-addressed row of the
-VMEM-resident packed buffer.  Sort-then-segment-copy used to be two payload
-passes; folding the permutation into the gather makes "each ray gets read
-exactly once and written exactly once" (§4.2.1/§6.1) hold through the
-marshal step too.
+``out[i] = packed[src[i]]`` in one gather.  Sort-then-segment-copy used to be
+two payload passes; folding the permutation into the gather makes "each ray
+gets read exactly once and written exactly once" (§4.2.1/§6.1) hold through
+the marshal step too.
 
-``marshal`` — the two-pass formulation kept for cross-validation: gather each
+``marshal`` — the two-pass formulation kept for cross-validation: copy each
 peer's *contiguous* segment of an already-sorted buffer into its fixed
-(peer_capacity,) slot via scalar-prefetched dynamic slices (the TPU analogue
-of the paper's observation that RDMA needs "single, consistent blocks of
-(GPU) data").
+(peer_capacity,) slot (the TPU analogue of the paper's observation that RDMA
+needs "single, consistent blocks of (GPU) data").
 
-``unmarshal``: the inverse — scatter received (R, S) blocks into a compact
-buffer at data-dependent offsets via dynamic-slice stores.  Segments are
-written whole; lanes past the per-peer count are masked by a
-load-blend-store (grid steps are sequential, so the read-modify-write is
-race-free).  A trash tail of S rows absorbs receiver-side overflow, keeping
-the §3.3 drop semantics.
+``unmarshal``: the inverse — land received (R, S) blocks in a compact buffer
+at data-dependent offsets.  Rows past a block's count, or past ``capacity``,
+are never written (§3.3 drop semantics); every row no block claims is zero.
 
 Payload layout: all kernels act on the packed wire format of
 ``core.types.pack_payload`` — the whole work-item pytree bitcast into one
 (C, words) uint32 buffer, mirroring the paper's "trivially copyable struct"
-contract on the wire.
+contract on the wire.  The buffers never enter VMEM: at deployment capacity
+(C = 2²⁰ rows) they are hundreds of MB.  They stay in HBM and rows move by
+DMA — one row per DMA for the gather, and for the block copies a run of
+``n`` rows split into its binary digits (≤ log2 S + 1 DMAs of static power-of-
+two length each).  A DMA slice must span whole 128-lane tiles, so the row
+width is padded to 128 words around the call (``kernels.pad_lanes``).
 """
 from __future__ import annotations
 
@@ -37,13 +36,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import sds
+from repro.kernels import call, pad_lanes, sds
+
+_SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+# indices per grid step: one (1024,) int32 block, XLA's tiling of a 1-D
+# int32 vector — an SMEM block must match it
+IDX_BLOCK = 1024
 
 
-def _marshal_kernel(off_ref, in_ref, out_ref, *, slot):
+def _copy_run(src_ref, dst_ref, src0, dst0, n, sem, *, max_rows):
+    """Copy rows ``[src0, src0+n)`` of ``src_ref`` to ``[dst0, dst0+n)`` of
+    ``dst_ref`` (both HBM), ``0 <= n <= max_rows``: one static-length DMA per
+    set bit of ``n``."""
+    for b in range(max(1, max_rows.bit_length()) - 1, -1, -1):
+        size = 1 << b
+        hi = (n >> (b + 1)) << (b + 1)  # rows already covered by higher bits
+
+        @pl.when((n & size) != 0)
+        def _():
+            cp = pltpu.make_async_copy(
+                src_ref.at[pl.ds(src0 + hi, size)],
+                dst_ref.at[pl.ds(dst0 + hi, size)],
+                sem,
+            )
+            cp.start()
+            cp.wait()
+
+
+def _marshal_kernel(off_ref, in_ref, out_ref, sem, *, slot):
     r = pl.program_id(0)
-    start = off_ref[r]
-    out_ref[...] = in_ref[pl.ds(start, slot), :][None]
+    cp = pltpu.make_async_copy(in_ref.at[pl.ds(off_ref[r], slot)], out_ref.at[r], sem)
+    cp.start()
+    cp.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("num_ranks", "slot", "interpret"))
@@ -60,32 +84,51 @@ def marshal(
     if slot > cap:
         raise ValueError(f"peer slot {slot} exceeds capacity {cap}")
     off = jnp.clip(offsets.astype(jnp.int32), 0, cap - slot)
-    return pl.pallas_call(
-        functools.partial(_marshal_kernel, slot=slot),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+    src = pad_lanes(sorted_flat)
+    dp = src.shape[1]
+
+    def kernel(off, src):
+        return pl.pallas_call(
+            functools.partial(_marshal_kernel, slot=slot),
             grid=(num_ranks,),
-            in_specs=[pl.BlockSpec((cap, d), lambda r, off: (0, 0))],
-            out_specs=pl.BlockSpec((1, slot, d), lambda r, off: (r, 0, 0)),
-        ),
-        out_shape=sds((num_ranks, slot, d), sorted_flat.dtype, sorted_flat, off),
-        interpret=interpret,
-    )(off, sorted_flat)
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=sds((num_ranks, slot, dp), src.dtype, src, off),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+            compiler_params=_SEQUENTIAL,
+            interpret=interpret,
+        )(off, src)
+
+    (out,) = call(kernel, off, src, interpret=interpret)
+    return out[:, :, :d]
 
 
-def _gather_rows_kernel(idx_ref, in_ref, out_ref, *, tile):
-    i = pl.program_id(0)
-    for t in range(tile):  # static unroll: `tile` dynamic row copies per step
-        out_ref[pl.ds(t, 1), :] = in_ref[pl.ds(idx_ref[i * tile + t], 1), :]
+def _gather_rows_kernel(idx_ref, src_ref, out_ref, sem, *, tile):
+    base = pl.program_id(0) * tile
+
+    def start(t, carry):
+        pltpu.make_async_copy(
+            src_ref.at[pl.ds(idx_ref[t], 1)], out_ref.at[pl.ds(base + t, 1)], sem
+        ).start()
+        return carry
+
+    def wait(t, carry):  # every copy moves one row: any one-row descriptor
+        pltpu.make_async_copy(src_ref.at[pl.ds(0, 1)], out_ref.at[pl.ds(0, 1)], sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, tile, start, 0)
+    jax.lax.fori_loop(0, tile, wait, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows(
     src: jax.Array,  # (C, D) packed payload
     row_idx: jax.Array,  # (N,) int32 source row per output row (clamped)
     *,
     interpret: bool = False,
-    tile: int = 8,
 ) -> jax.Array:
     """The fused single-pass marshal: ``out[i] = src[row_idx[i]]``.
 
@@ -93,47 +136,45 @@ def gather_rows(
     send-slot layout (``perm[off[r] + s]`` for the flat exchange; either
     stage's layout for the hierarchical one), so this one gather subsumes
     what used to be payload-sort-then-segment-copy — each payload row is read
-    exactly once and written exactly once.  The index vector lands in SMEM by
-    scalar prefetch; each grid step copies a TILE of ``tile`` (default 8)
-    dynamically-addressed rows of the VMEM-resident packed buffer, amortising
-    the Mosaic per-step grid overhead the one-row-per-step formulation paid
-    (rows are not contiguous, unlike :func:`marshal`, because the sort
-    permutation is folded in).  ``row_idx`` is padded up to a whole tile; the
+    exactly once and written exactly once.  Each grid step brings
+    ``IDX_BLOCK`` indices into SMEM and issues one row DMA per index, HBM to HBM, then
+    waits for all of them.  ``row_idx`` is padded up to a whole tile; the
     padded tail is cut from the result.
     """
     cap, d = src.shape
     n = row_idx.shape[0]
+    tile = IDX_BLOCK
     idx = jnp.clip(row_idx.astype(jnp.int32), 0, cap - 1)
     n_pad = -(-n // tile) * tile
     if n_pad != n:
         idx = jnp.concatenate([idx, jnp.zeros((n_pad - n,), jnp.int32)])
-    out = pl.pallas_call(
-        functools.partial(_gather_rows_kernel, tile=tile),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+    src = pad_lanes(src)
+
+    def kernel(idx, src):
+        return pl.pallas_call(
+            functools.partial(_gather_rows_kernel, tile=tile),
             grid=(n_pad // tile,),
-            in_specs=[pl.BlockSpec((cap, d), lambda i, idx: (0, 0))],
-            out_specs=pl.BlockSpec((tile, d), lambda i, idx: (i, 0)),
-        ),
-        out_shape=sds((n_pad, d), src.dtype, src, idx),
-        interpret=interpret,
-    )(idx, src)
-    return out[:n] if n_pad != n else out
+            in_specs=[
+                pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=sds((n_pad, src.shape[1]), src.dtype, src, idx),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+            compiler_params=_SEQUENTIAL,
+            interpret=interpret,
+        )(idx, src)
+
+    (out,) = call(kernel, idx, src, interpret=interpret)
+    return out[:n, :d]
 
 
-def _unmarshal_kernel(off_ref, cnt_ref, in_ref, out_ref, *, slot):
+def _unmarshal_kernel(off_ref, n_ref, in_ref, zero_ref, out_ref, sem, *, max_rows):
+    del zero_ref  # aliased to out_ref: the rows no block writes stay zero
     r = pl.program_id(0)
-
-    @pl.when(r == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    start = off_ref[r]
-    cnt = cnt_ref[r]
-    blk = in_ref[0]
-    cur = out_ref[pl.ds(start, slot), :]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (slot, 1), 0)
-    out_ref[pl.ds(start, slot), :] = jnp.where(lane < cnt, blk, cur)
+    _copy_run(
+        in_ref.at[r], out_ref, 0, off_ref[r], n_ref[r], sem, max_rows=max_rows
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
@@ -147,19 +188,30 @@ def unmarshal(
 ) -> jax.Array:
     """Returns the (capacity, D) compacted receive buffer (drop-tail applied)."""
     num_ranks, slot, d = recv_buf.shape
-    # Trash tail: segments that start past `capacity` (or spill over it) write
-    # into the extra S rows, which are cut off below — §3.3 drop semantics.
-    padded = capacity + slot
     off = jnp.clip(recv_offsets.astype(jnp.int32), 0, capacity)
-    out = pl.pallas_call(
-        functools.partial(_unmarshal_kernel, slot=slot),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+    # rows of a block that would land at/past `capacity` are never written —
+    # §3.3 drop semantics
+    n = jnp.clip(jnp.minimum(recv_counts.astype(jnp.int32), capacity - off), 0, slot)
+    buf = pad_lanes(recv_buf)
+    zeros = jnp.zeros((capacity, buf.shape[2]), buf.dtype)
+
+    def kernel(off, n, buf, zeros):
+        return pl.pallas_call(
+            functools.partial(_unmarshal_kernel, max_rows=min(slot, capacity)),
             grid=(num_ranks,),
-            in_specs=[pl.BlockSpec((1, slot, d), lambda r, off, cnt: (r, 0, 0))],
-            out_specs=pl.BlockSpec((padded, d), lambda r, off, cnt: (0, 0)),
-        ),
-        out_shape=sds((padded, d), recv_buf.dtype, recv_buf, off),
-        interpret=interpret,
-    )(off, recv_counts.astype(jnp.int32), recv_buf)
-    return out[:capacity]
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=sds(zeros.shape, buf.dtype, buf, off, n, zeros),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+            input_output_aliases={3: 0},
+            compiler_params=_SEQUENTIAL,
+            interpret=interpret,
+        )(off, n, buf, zeros)
+
+    (out,) = call(kernel, off, n, buf, zeros, interpret=interpret)
+    return out[:, :d]

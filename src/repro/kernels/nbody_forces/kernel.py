@@ -3,11 +3,14 @@
 Computes softened monopole accelerations of N target particles due to M
 sources (sources = local particles ∪ received VirtualParticles).  Classic
 two-level tiling: grid (N/TI, M/TJ) with the source loop innermost; the
-(TI, 3) accumulator lives in the revisited output block (sequential TPU grid
-⇒ safe).  All math is rank-2 broadcasts on the VPU with TI×TJ inner shapes —
-multiples of 128 keep the lanes full.
+accumulator lives in the revisited output block (sequential TPU grid ⇒
+safe).  Targets run along lanes and sources along sublanes: the kernel sees
+target coordinates as a ``(3, TI)`` block, sources as ``(TJ, 3)`` and masses
+as ``(TJ, 1)``, so every pairwise quantity is one ``(TJ, TI)`` tile per
+coordinate and the source sum is a sublane reduction.  The wrapper
+transposes targets in and accelerations out.
 
-VMEM per step: TI·4·4 + TJ·4·4 + TI·TJ·(3+1)·4 B ≈ 1.1 MB at TI=TJ=256.
+VMEM per step: about six (TJ, TI) f32 tiles ≈ 1.5 MB at TI=TJ=256.
 """
 from __future__ import annotations
 
@@ -17,24 +20,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import sds
+from repro.kernels import call, sds
 
 
-def _forces_kernel(xi_ref, xj_ref, mj_ref, out_ref, *, eps2, nj_steps):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+def _forces_kernel(xi_ref, xj_ref, mj_ref, out_ref, *, eps2):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    xi = xi_ref[...]  # (TI, 3)
-    xj = xj_ref[...]  # (TJ, 3)
-    mj = mj_ref[...]  # (TJ,)
-    dx = xj[None, :, :] - xi[:, None, :]  # (TI, TJ, 3)
-    r2 = jnp.sum(dx * dx, axis=-1) + eps2  # (TI, TJ)
+    xi = xi_ref[...]  # (3, TI) targets, one coordinate per row
+    xj = xj_ref[...]  # (TJ, 3) sources
+    dx = [xj[:, k:k + 1] - xi[k:k + 1, :] for k in range(3)]  # (TJ, TI) each
+    r2 = dx[0] * dx[0] + dx[1] * dx[1] + dx[2] * dx[2] + eps2
     inv = jax.lax.rsqrt(r2)
-    w = mj[None, :] * inv * inv * inv  # G·m_j / r³ (G folded in by caller)
-    out_ref[...] += jnp.sum(w[:, :, None] * dx, axis=1)
+    w = mj_ref[...] * inv * inv * inv  # G·m_j / r³ (G folded in by caller)
+    for k in range(3):
+        out_ref[k:k + 1, :] += jnp.sum(w * dx[k], axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("eps2", "ti", "tj", "interpret"))
@@ -57,15 +58,22 @@ def pairwise_accel(
     while m % tj:
         tj //= 2
     grid = (n // ti, m // tj)
-    return pl.pallas_call(
-        functools.partial(_forces_kernel, eps2=eps2, nj_steps=grid[1]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ti, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((tj, 3), lambda i, j: (j, 0)),
-            pl.BlockSpec((tj,), lambda i, j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((ti, 3), lambda i, j: (i, 0)),
-        out_shape=sds((n, 3), jnp.float32, xi, xj, mj),
-        interpret=interpret,
-    )(xi, xj, mj)
+    xi_t = xi.T
+    mj = mj.reshape(m, 1)
+
+    def kernel(xi_t, xj, mj):
+        return pl.pallas_call(
+            functools.partial(_forces_kernel, eps2=eps2),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((3, ti), lambda i, j: (0, i)),
+                pl.BlockSpec((tj, 3), lambda i, j: (j, 0)),
+                pl.BlockSpec((tj, 1), lambda i, j: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((3, ti), lambda i, j: (0, i)),
+            out_shape=sds((3, n), jnp.float32, xi_t, xj, mj),
+            interpret=interpret,
+        )(xi_t, xj, mj)
+
+    (out,) = call(kernel, xi_t, xj, mj, interpret=interpret)
+    return out.T

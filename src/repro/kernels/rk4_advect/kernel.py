@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import sds
+from repro.kernels import call, sds
 
 ABC, TORNADO, TAYLOR_GREEN = 0, 1, 2
 
@@ -74,17 +74,21 @@ def rk4_step(
     tile = min(tile, n)
     while n % tile:
         tile //= 2
-    return pl.pallas_call(
-        functools.partial(_rk4_kernel, dt=dt, field_id=field_id, params=params),
-        grid=(n // tile,),
-        in_specs=[pl.BlockSpec((tile, 3), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((tile, 3), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 3), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            sds((n, 3), jnp.float32, pos),
-            sds((n, 3), jnp.float32, pos),
-        ],
-        interpret=interpret,
-    )(pos)
+
+    def kernel(pos):
+        return pl.pallas_call(
+            functools.partial(_rk4_kernel, dt=dt, field_id=field_id, params=params),
+            grid=(n // tile,),
+            in_specs=[pl.BlockSpec((tile, 3), lambda i: (i, 0))],
+            out_specs=[
+                pl.BlockSpec((tile, 3), lambda i: (i, 0)),
+                pl.BlockSpec((tile, 3), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                sds((n, 3), jnp.float32, pos),
+                sds((n, 3), jnp.float32, pos),
+            ],
+            interpret=interpret,
+        )(pos)
+
+    return call(kernel, pos, interpret=interpret)

@@ -2,21 +2,16 @@
 
 The paper launches a CUDA kernel that writes ``(dest << 32) | i`` uint64 keys
 and then radix-sorts them with cub.  The TPU adaptation packs into 32 bits
-(rank count ≤ 1024 needs ≤ 10 bits; x64 is off in JAX anyway) and — because
+(rank count ≤ 1023 needs ≤ 10 bits; x64 is off in JAX anyway) and — because
 the key distribution is tiny — replaces the generic radix sort with a
-counting sort whose histogram is computed *in the same pass* as the key pack,
-mapping the one-hot contraction onto the MXU:
+counting sort whose histogram is computed *in the same pass* as the key pack.
 
-    hist[r] = Σ_lanes one_hot(dest_clean[lane], R+1)          (T,R+1)·(T,)→(R+1,)
-
-Tiling: the destination vector is processed in VMEM tiles of ``TILE`` lanes;
-the histogram output block is revisited by every grid step (TPU grid steps
-run sequentially, so accumulation into the output block is safe — the
-canonical Pallas reduction pattern).
-
-VMEM budget per step: TILE·4 B (dest) + TILE·4 B (keys) + TILE·(R+1)·4 B
-(one-hot) — for TILE=2048, R=512: ~4.2 MB, comfortably inside the ~16 MB
-VMEM of a v5e core; matmul dims are multiples of 128 when TILE is.
+Tiling: the destination vector is viewed as ``(C/128, 128)`` and walked in
+blocks of ``block_rows`` rows.  The histogram is an ``(8, 128)`` int32 block
+(bucket ``b`` at flat position ``b``) revisited by every grid step — TPU grid
+steps run sequentially, so accumulation into the output block is safe (the
+canonical Pallas reduction pattern, shared with ``kernels/bucket_scatter``).
+Keys are built in int32 and bitcast to uint32 outside the kernel.
 """
 from __future__ import annotations
 
@@ -25,72 +20,88 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import sds
+from repro.kernels import LANES, call, sds
+from repro.kernels.bucket_scatter.kernel import (
+    MAX_BUCKETS,
+    block_rows_for,
+    lane_index,
+    lane_rows,
+    lane_total,
+)
+
+_SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
-def _pack_hist_kernel(dest_ref, count_ref, keys_ref, hist_ref, *, num_ranks, idx_bits, tile):
+def _pack_hist_kernel(
+    count_ref, dest_ref, keys_ref, hist_ref, *, num_ranks, idx_bits, block_rows
+):
     step = pl.program_id(0)
-    lane0 = step * tile
-    lane = lane0 + jax.lax.broadcasted_iota(jnp.int32, (tile,), 0)
+    lane = lane_index(step, block_rows)
     d = dest_ref[...]
-    count = count_ref[0]
-    valid = (lane < count) & (d >= 0) & (d < num_ranks)
+    valid = (lane < count_ref[0]) & (d >= 0) & (d < num_ranks)
     d_clean = jnp.where(valid, d, num_ranks)
-    keys_ref[...] = (d_clean.astype(jnp.uint32) << idx_bits) | lane.astype(jnp.uint32)
-
-    # One-hot histogram on the MXU: ones(T) · one_hot(d,(T,R+1)) → (R+1,)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, num_ranks + 1), 1)
-    onehot = (d_clean[:, None] == r_iota).astype(jnp.float32)
-    part = jax.lax.dot_general(
-        jnp.ones((tile,), jnp.float32),
-        onehot,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.int32)
+    keys_ref[...] = (d_clean << idx_bits) | lane
 
     @pl.when(step == 0)
     def _init():
-        hist_ref[...] = part
+        hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    @pl.when(step > 0)
-    def _accum():
-        hist_ref[...] += part
+    bucket_pos = lane_index(0, 8)
+
+    def bucket(b, counts):
+        n = lane_total((d_clean == b).astype(jnp.int32))
+        return counts + jnp.where(bucket_pos == b, n, 0)
+
+    hist_ref[...] += jax.lax.fori_loop(
+        0, num_ranks + 1, bucket, jnp.zeros((8, LANES), jnp.int32)
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("num_ranks", "idx_bits", "tile", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("num_ranks", "idx_bits", "block_rows", "interpret")
+)
 def pack_and_histogram(
     dest: jax.Array,
     count: jax.Array,
     *,
     num_ranks: int,
     idx_bits: int,
-    tile: int = 2048,
+    block_rows: int = 256,
     interpret: bool = False,
 ):
     """Returns (keys uint32 (C,), hist int32 (R+1,)); invalid lanes → dest R."""
     cap = dest.shape[0]
-    tile = min(tile, cap)
-    if cap % tile:
-        raise ValueError(f"capacity {cap} not divisible by tile {tile}")
-    grid = (cap // tile,)
-    kern = functools.partial(
-        _pack_hist_kernel, num_ranks=num_ranks, idx_bits=idx_bits, tile=tile
-    )
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((num_ranks + 1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            sds((cap,), jnp.uint32, dest, count),
-            sds((num_ranks + 1,), jnp.int32, dest, count),
-        ],
-        interpret=interpret,
-    )(dest, count.reshape(1).astype(jnp.int32))
+    if num_ranks + 1 > MAX_BUCKETS:
+        raise ValueError(
+            f"num_ranks + 1 ({num_ranks + 1}) exceeds the kernel's "
+            f"{MAX_BUCKETS}-bucket histogram block"
+        )
+    rows = block_rows_for(cap, block_rows)
+    d2 = lane_rows(dest.astype(jnp.int32), rows, -1)
+    cnt = jnp.minimum(count.astype(jnp.int32), cap).reshape(1)
+    blk = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+
+    def kernel(cnt, d2):
+        return pl.pallas_call(
+            functools.partial(
+                _pack_hist_kernel, num_ranks=num_ranks, idx_bits=idx_bits,
+                block_rows=rows,
+            ),
+            grid=(d2.shape[0] // rows,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk],
+            out_specs=[blk, pl.BlockSpec((8, LANES), lambda i: (0, 0))],
+            out_shape=[
+                sds(d2.shape, jnp.int32, d2, cnt),
+                sds((8, LANES), jnp.int32, d2, cnt),
+            ],
+            compiler_params=_SEQUENTIAL,
+            interpret=interpret,
+        )(cnt, d2)
+
+    keys, hist = call(kernel, cnt, d2, interpret=interpret)
+    keys = jax.lax.bitcast_convert_type(keys.reshape(-1)[:cap], jnp.uint32)
+    hist = hist.reshape(-1)[: num_ranks + 1]
+    # padding lanes fell into the invalid bucket R; they are not lanes
+    return keys, hist.at[num_ranks].add(cap - d2.size)

@@ -27,7 +27,6 @@ def sort_permutation(
     count: jax.Array,
     num_ranks: int,
     *,
-    tile: int = 2048,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Pallas-path equivalent of ``core.sorting.sort_permutation``: key pack +
@@ -40,12 +39,8 @@ def sort_permutation(
     ib = _idx_bits(cap)
     if (num_ranks + 1).bit_length() + ib > 32:
         raise ValueError("packed key exceeds 32 bits; reduce capacity or ranks")
-    # pick a tile that divides the capacity
-    t = min(tile, cap)
-    while cap % t:
-        t //= 2
     keys, hist = K.pack_and_histogram(
-        dest, count, num_ranks=num_ranks, idx_bits=ib, tile=t, interpret=interpret
+        dest, count, num_ranks=num_ranks, idx_bits=ib, interpret=interpret
     )
     sorted_keys = jax.lax.sort(keys)
     d_sorted = (sorted_keys >> ib).astype(jnp.int32)
@@ -58,7 +53,6 @@ def sort_permutation_hierarchical(
     count: jax.Array,
     level_sizes,
     *,
-    tile: int = 2048,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Pallas-path equivalent of ``core.sorting.sort_permutation_hierarchical``
@@ -81,7 +75,7 @@ def sort_permutation_hierarchical(
     for a in level_sizes:
         num_ranks *= a
     perm, _d_sorted, hist = sort_permutation(
-        dest, count, num_ranks, tile=tile, interpret=interpret
+        dest, count, num_ranks, interpret=interpret
     )
     return perm, hist[:num_ranks].reshape(level_sizes)
 
@@ -92,12 +86,11 @@ def sort_by_destination(
     count: jax.Array,
     num_ranks: int,
     *,
-    tile: int = 2048,
     interpret: bool | None = None,
 ) -> Tuple[Any, jax.Array, jax.Array]:
     """Pallas-path equivalent of core.sorting.sort_by_destination."""
     perm, d_sorted, hist = sort_permutation(
-        dest, count, num_ranks, tile=tile, interpret=interpret
+        dest, count, num_ranks, interpret=interpret
     )
     sorted_items = T.tree_take(items, perm)
     return sorted_items, d_sorted, hist

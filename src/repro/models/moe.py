@@ -32,8 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.core import DISCARD, ForwardConfig, enqueue, forward_work, make_queue, work_item
 from repro.models.common import MODEL_AXIS, ModelConfig, ParamDef, shard
 
@@ -230,7 +228,7 @@ def moe_rafi_ep(params, x, cfg: ModelConfig, *, mesh) -> Tuple[jax.Array, jax.Ar
         return y_all, drops[None]
 
     baxes = tuple(a for a in mesh.axis_names if a != MODEL_AXIS)  # pod?, data
-    y, drops = compat.shard_map(
+    y, drops = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(

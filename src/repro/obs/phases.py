@@ -110,7 +110,7 @@ def profile_phases(
     phase_us: Dict[str, float] = {}
     for key, kernel in phases:
         f = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 kernel, mesh=mesh, in_specs=P(axes), out_specs=P(axes)
             )
         )
@@ -395,7 +395,7 @@ def _ragged_phases(cfg, n_emit, cap, proto) -> Tuple:
         ("marshal", marshal_kernel),
         ("count_collective", count_collective_kernel),
     ]
-    if compat.HAS_RAGGED_ALL_TO_ALL:
+    if compat.ragged_executes():
         def payload_collective_kernel(x):
             me = jax.lax.axis_index(axes)
             n = max(n_emit, R)
@@ -404,7 +404,7 @@ def _ragged_phases(cfg, n_emit, cap, proto) -> Tuple:
             ).reshape(n, words)
             seg = jnp.full((R,), n // R, jnp.int32)
             off = jnp.cumsum(seg) - seg
-            recv = compat.ragged_all_to_all(
+            recv = jax.lax.ragged_all_to_all(
                 buf, jnp.zeros_like(buf),
                 input_offsets=off, send_sizes=seg,
                 output_offsets=off, recv_sizes=seg,

@@ -17,9 +17,9 @@ Two ways to turn it on:
 
 * explicitly — ``with trace.capture() as tr: ...; tr.save(path)``;
 * ambiently — set ``RAFI_TRACE=1`` (record only) or ``RAFI_TRACE=/path.json``
-  (record + flush the Perfetto JSON there at process exit), mirroring the
-  ``RAFI_PALLAS_INTERPRET`` CI toggle.  The env tracer is installed lazily
-  on the first ``enabled()`` check so merely importing repro costs nothing.
+  (record + flush the Perfetto JSON there at process exit).  The env tracer
+  is installed lazily on the first ``enabled()`` check so merely importing
+  repro costs nothing.
 
 Export is Chrome/Perfetto ``trace_event`` JSON (``chrome://tracing``,
 https://ui.perfetto.dev): spans are complete ``"X"`` events, instants are

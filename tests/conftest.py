@@ -4,12 +4,9 @@ Tests that exercise collectives need a real multi-device mesh, so we ask the
 CPU platform for 8 devices — enough for an interesting (2, 4) mesh.  The
 production 512-device setting lives ONLY in ``repro.launch.dryrun`` (the
 dry-run harness), never here: smoke tests and benchmarks are written to work
-at whatever small device count this gives.
-
-All version-sensitive JAX surface (``AxisType``, ``jax.shard_map``,
-``ragged_all_to_all``) is reached through ``repro.compat`` — tests that need
-a feature the installed JAX lacks must ``pytest.skip`` on the ``HAS_*``
-flags, never fail at import.
+at whatever small device count this gives.  Pallas kernels run in interpret
+mode here, because the backend is not a TPU; ``tests/test_tpu_compile.py``
+compiles them for a described v5e instead.
 """
 import os
 
@@ -22,14 +19,6 @@ from repro import compat
 
 
 def pytest_configure(config):
-    config.addinivalue_line(
-        "markers",
-        "pallas_interpret: force Pallas kernels into interpret mode for this "
-        "test (sets RAFI_PALLAS_INTERPRET=1) so tier-1 exercises the kernel "
-        "code paths — bucket_scatter, sort_keys, marshal — without a TPU.  "
-        "On the CPU container interpret is already the default; on a TPU "
-        "runner the marker keeps these tests backend-independent.",
-    )
     config.addinivalue_line(
         "markers",
         "telemetry: exercises the ISSUE-5 traffic-telemetry / adaptive-"
@@ -68,8 +57,8 @@ def pytest_configure(config):
         "markers",
         "obs: exercises the ISSUE-10 observation law (repro.obs) — host-side "
         "span tracing, metrics export, and the flight-data analyzer.  The "
-        "marker also turns the ambient tracer ON via RAFI_TRACE=1 (the env "
-        "toggle mirroring RAFI_PALLAS_INTERPRET), so marked tests run every "
+        "marker also turns the ambient tracer ON via RAFI_TRACE=1, so marked "
+        "tests run every "
         "drive entry point with its trace hooks live.  Part of tier-1; CI "
         "can select with `-m obs`.",
     )
@@ -91,17 +80,9 @@ def pytest_configure(config):
 
 
 @pytest.fixture(autouse=True)
-def _pallas_interpret_toggle(request, monkeypatch):
-    """Honour the ``pallas_interpret`` marker via the env var that
-    ``repro.kernels.default_interpret`` consults (the CI toggle)."""
-    if request.node.get_closest_marker("pallas_interpret"):
-        monkeypatch.setenv("RAFI_PALLAS_INTERPRET", "1")
-
-
-@pytest.fixture(autouse=True)
 def _rafi_trace_toggle(request, monkeypatch):
     """Honour the ``obs`` marker via the ``RAFI_TRACE`` env toggle that
-    ``repro.obs.trace`` consults lazily (mirrors ``RAFI_PALLAS_INTERPRET``):
+    ``repro.obs.trace`` consults lazily :
     marked tests run with the ambient tracer installed; teardown uninstalls
     it and restores the lazy env check so other tests stay untraced."""
     if not request.node.get_closest_marker("obs"):

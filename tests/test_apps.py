@@ -34,6 +34,15 @@ class TestVopat:
         assert img.std() > 0.01  # not a constant field
         assert stats["rounds"] < 512
 
+    def test_truncated_frame_reports_not_done(self, mesh1):
+        """A frame cut at ``max_rounds`` must say so: ``done`` is False and
+        the image differs from the finished one."""
+        full, s_full = vopat.render(mesh1, self.scene)
+        cut, s_cut = vopat.render(mesh1, self.scene, max_rounds=2)
+        assert s_full["done"] and s_full["rounds"] > 2
+        assert not s_cut["done"] and s_cut["rounds"] == 2
+        assert not np.array_equal(full, cut)
+
     def test_spp_accumulation_close(self, mesh1, mesh8):
         scene = vopat.VopatScene(width=8, height=8, spp=4)
         i1, _ = vopat.render(mesh1, scene)

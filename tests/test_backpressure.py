@@ -37,7 +37,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.chaos import (
     boundary_digests,
     expected_by_rank,
@@ -250,7 +249,7 @@ def test_zero_credit_round_ships_no_payload(mesh8):
         )
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P(), P("data"), P("data"), P("data"), P("data")),
         )

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import ForwardConfig, enqueue, forward_work, make_queue
 from repro.core import types as T
 from repro.roofline.analysis import collective_ops, group_axis
@@ -43,7 +42,7 @@ def _lower_one_round(mesh8, cfg):
         return nq.count[None], total, nq.items.tmin
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P(), P("data")),
         )
@@ -75,8 +74,6 @@ def test_padded_round_has_one_payload_and_one_count_collective(mesh8, use_pallas
 
 
 def test_ragged_round_has_one_payload_and_one_count_collective(mesh8):
-    if not compat.HAS_RAGGED_ALL_TO_ALL:
-        pytest.skip("installed JAX has no lax.ragged_all_to_all")
     cfg = ForwardConfig("data", R, CAP, exchange="ragged")
     ops = collective_ops(_lower_one_round(mesh8, cfg))
     ragged = [b for k, b in ops if k == "ragged-all-to-all"]
@@ -102,7 +99,7 @@ def _lower_hier_round(mesh, cfg):
         return nq.count[None], total, nq.items.tmin
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=P(axes),
             out_specs=(P(axes), P(), P(axes)),
         )
@@ -268,7 +265,7 @@ def _lower_round_with_telemetry(mesh, cfg, axes):
         TM.make_stats(TM.num_tiers(cfg), cfg.telemetry_buckets),
     )
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=P(axes),
             out_specs=(P(axes), P(), P(axes), stats_spec),
         )
@@ -327,7 +324,7 @@ def _lower_round_any_overflow(mesh, cfg, axes):
         return nq.count[None], total, nq.items.tmin, age
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=P(axes),
             out_specs=(P(axes), P(), P(axes), P(axes)),
         )
@@ -380,7 +377,7 @@ def _lower_round_with_health(mesh, cfg, axes):
         return nq.count[None], total, nq.items.tmin
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=(P(axes), P()),
             out_specs=(P(axes), P(), P(axes)),
         )
@@ -497,7 +494,7 @@ def test_cycle_hop_ships_one_packed_buffer(mesh8):
         return nq.count[None], na.count[None], nq.items.tmin
 
     txt = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P("data"), P("data")),
         )
@@ -579,7 +576,7 @@ def _lower_round_any_flow(mesh, cfg, axes):
 
     spec = P(axes)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=spec,
             out_specs=(spec, P(), spec, spec, spec),
         )
@@ -670,8 +667,6 @@ def test_tracing_leaves_lowering_bit_identical(request, case):
     from repro.obs import trace as OT
 
     fixture, kw = _OBS_CASES[case]
-    if case == "ragged" and not compat.HAS_RAGGED_ALL_TO_ALL:
-        pytest.skip("installed JAX has no lax.ragged_all_to_all")
     mesh = request.getfixturevalue(fixture)
     axes = "data" if fixture == "mesh8" else ("pod", "node", "device")
     cfg = ForwardConfig(axes, R, CAP, **kw)
@@ -738,22 +733,22 @@ def test_traced_metered_drive_leaves_lowering_bit_identical(mesh8):
     )
 
 
-# The pre-refactor (PR 7) lowered HLO of one forward round, snapshotted with
-# THIS harness's kernel before exchange.py was rebuilt on the stage graph.
-# ``pipeline_shards=1`` must reproduce it byte for byte — the stage-graph
-# refactor and the bulk fast path are provably the same program.  The ragged
-# backend has no golden: this container's JAX predates ragged_all_to_all, so
-# the pre-refactor code never lowered it here (its S=1 path is covered by
-# test_ragged_round_has_one_payload_and_one_count_collective when present).
+# The lowered HLO of one forward round, pinned as SHA-256 digests of the
+# StableHLO text this harness's kernel lowers to on JAX 0.9.0.  The PR-8
+# stage-graph refactor was proven byte-identical to the monolith it replaced
+# against digests taken the same way; these re-pin that snapshot on the
+# installed JAX, so any change to what one round lowers to — a refactor that
+# was meant to be free, or a new JAX — shows up here first.  The telemetry
+# round lowers to the SAME program as the plain one: its stats are unused.
 _PRE_REFACTOR_SHA256 = {
-    "padded_sort": "f16365d26b599b27bd1a166d74fceaa5f90259332998d16b71d72d4439220717",
-    "padded_scatter": "0d857013e3f21a9a541a26394f81fe9a9f31733f99428977d1bfe7e98e732f79",
-    "padded_retain": "a8689e0fbf084f193636618b2566b1292aa82c9aa3f6e03f9423b91f70ae5b9d",
-    "padded_telemetry": "f16365d26b599b27bd1a166d74fceaa5f90259332998d16b71d72d4439220717",
-    "onehot": "fac130fe7f8774f30b03413382c9a995a8ebf2c949fa1e0c940acbde1297f660",
-    "hier3_sort": "cadd1301d5b03a763651c7898ffd6867eca0578c85f8a96bf1ab323cf918ef55",
-    "hier3_scatter": "e7598ae0e9d686f722ce48b9d3646a15ca4b2099cf81aa153c4bfc8f9bf81fe3",
-    "hier3_retain": "b643d76cf02f463482cba167be465431a026df7d38c4354bceaeb4bda891431d",
+    "padded_sort": "b9ff2802e1d523eea6749d19aeb694aff1fd0f45cd626bebbc7981bd9fbb0615",
+    "padded_scatter": "4731458f9e7d41a38d68875443b71440739138a3bd1691a361212b5adf3b91f2",
+    "padded_retain": "93fd34828d386c3b067ad6d667e11deb525107f20dde135123dfba2d54dde12a",
+    "padded_telemetry": "b9ff2802e1d523eea6749d19aeb694aff1fd0f45cd626bebbc7981bd9fbb0615",
+    "onehot": "d6e4464d803b47854a1df6ec5ee838cfcf91403ec1fddd7a9f7b36ea65f39b9d",
+    "hier3_sort": "c7c035c54146f4f760500ec9d473d58c88925652c919d8710711e0b0063f7afd",
+    "hier3_scatter": "26417c13fb7f24d9e795753c6d3222bd3358c01be99373944e764b3c1266da94",
+    "hier3_retain": "1d40f34ee5b0e819bca8e1db6765b2f48990cb95903b98d1ae6167289a7dfe8c",
 }
 
 _GOLDEN_CASES = {
@@ -795,22 +790,18 @@ def _lower_golden(mesh, cfg):
 
     spec = P(axes)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh, in_specs=spec, out_specs=(spec, P(), spec)
         )
     ).lower(jnp.arange(8.0)).as_text()
 
 
 @pytest.mark.pipeline
-@pytest.mark.skipif(
-    jax.__version__ != "0.4.37",
-    reason="golden HLO digests are pinned to the container's JAX lowering",
-)
 @pytest.mark.parametrize("case", sorted(_GOLDEN_CASES))
 def test_bulk_lowering_bitidentical_to_pre_refactor(request, case):
-    """ISSUE 8 acceptance: with ``pipeline_shards=1`` the stage-graph
-    exchange lowers BYTE-identically to the pre-refactor monolith — same
-    StableHLO text, so same compiled program, no trust required."""
+    """With ``pipeline_shards=1`` the stage-graph exchange lowers
+    BYTE-identically to the pinned program — same StableHLO text, so same
+    compiled program, no trust required."""
     import hashlib
 
     fixture, kw = _GOLDEN_CASES[case]
@@ -819,5 +810,5 @@ def test_bulk_lowering_bitidentical_to_pre_refactor(request, case):
     cfg = ForwardConfig(axes, R, CAP, pipeline_shards=1, **kw)
     got = hashlib.sha256(_lower_golden(mesh, cfg).encode()).hexdigest()
     assert got == _PRE_REFACTOR_SHA256[case], (
-        f"{case}: S=1 lowering diverged from the pre-refactor HLO"
+        f"{case}: S=1 lowering diverged from the pinned HLO"
     )

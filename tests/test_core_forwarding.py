@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 from repro.core import (
     DISCARD,
@@ -51,7 +50,7 @@ def _emit_and_forward(cfg, dest_of):
 
 def _run(mesh8, cfg, dest_of):
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             _emit_and_forward(cfg, dest_of),
             mesh=mesh8,
             in_specs=P("data"),
@@ -135,8 +134,6 @@ def test_receiver_capacity_overflow(mesh8):
 def test_ragged_exchange_lowers_with_ragged_all_to_all(mesh8):
     """XLA:CPU cannot run ragged-all-to-all; assert the TPU production path
     lowers to the dedicated op (the MPI_Alltoallv analogue)."""
-    if not compat.HAS_RAGGED_ALL_TO_ALL:
-        pytest.skip("installed JAX has no lax.ragged_all_to_all")
     cfg = ForwardConfig("data", R, CAP, exchange="ragged")
 
     def k(_x):
@@ -149,7 +146,7 @@ def test_ragged_exchange_lowers_with_ragged_all_to_all(mesh8):
         return nq.items.tmin
 
     low = jax.jit(
-        compat.shard_map(k, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
+        jax.shard_map(k, mesh=mesh8, in_specs=P("data"), out_specs=P("data"))
     ).lower(jnp.arange(8.0))
     assert "ragged_all_to_all" in low.as_text()
 
@@ -186,8 +183,8 @@ def test_multi_round_termination(mesh8):
         return acc[None], rounds[None], done[None]
 
     f = jax.jit(
-        compat.shard_map(drive, mesh=mesh8, in_specs=P("data"),
-                         out_specs=(P("data"), P("data"), P("data")))
+        jax.shard_map(drive, mesh=mesh8, in_specs=P("data"),
+                      out_specs=(P("data"), P("data"), P("data")))
     )
     acc, rounds, done = f(jnp.arange(8.0))
     assert float(np.asarray(acc).sum()) == 8 * 2 * 5.0
@@ -233,8 +230,8 @@ def test_drops_not_double_counted_when_round_fn_threads_queue_drops(mesh8):
         return q.drops[None], rounds[None]
 
     f = jax.jit(
-        compat.shard_map(drive, mesh=mesh8, in_specs=P("data"),
-                         out_specs=(P("data"), P("data")))
+        jax.shard_map(drive, mesh=mesh8, in_specs=P("data"),
+                      out_specs=(P("data"), P("data")))
     )
     drops, _rounds = f(jnp.arange(8.0))
     # 4 forwarding rounds × (6 emitted − 2 delivered) = 16 — NOT the
@@ -270,7 +267,7 @@ def test_max_rounds_cap_with_work_still_in_flight(mesh8):
         return q.count[None], q.drops[None], rounds[None], acc[None], done[None]
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             drive, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P("data"), P("data"), P("data"), P("data")),
         )
@@ -305,7 +302,7 @@ def test_rebalance_equalizes_load(mesh8):
         nq, total = rebalance(q, cfg)
         return nq.count[None], total
 
-    f = jax.jit(compat.shard_map(bal, mesh=mesh8, in_specs=P("data"), out_specs=(P("data"), P())))
+    f = jax.jit(jax.shard_map(bal, mesh=mesh8, in_specs=P("data"), out_specs=(P("data"), P())))
     counts, total = f(jnp.arange(8.0))
     counts = np.asarray(counts)
     assert int(total) == 48
@@ -329,7 +326,7 @@ def test_forward_on_joint_mesh_axes(mesh24):
         return nq.count[None], total
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel,
             mesh=mesh24,
             in_specs=P(("data", "model")),
@@ -362,7 +359,7 @@ def test_queue_cycling_delivers_everything(mesh8):
         absorbed, total = deliver_by_cycling(q, cfg)
         return absorbed.count[None], total, absorbed.items.pixel
 
-    f = jax.jit(compat.shard_map(kernel, mesh=mesh8, in_specs=P("data"),
+    f = jax.jit(jax.shard_map(kernel, mesh=mesh8, in_specs=P("data"),
                               out_specs=(P("data"), P(), P("data"))))
     counts, total, pixels = f(jnp.arange(8.0))
     assert int(total) == 8 * 6

@@ -15,13 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import DISCARD, ForwardConfig, WorkQueue, forward_work, work_item
 
 R, CAP = 8, 64
@@ -48,7 +44,7 @@ def _make_fn(mesh, cfg, axes=AXES):
         return nq.items.val, nq.items.src, nq.count[None], nq.drops[None], total
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             fwd, mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes)),
             out_specs=(P(axes), P(axes), P(axes), P(axes), P()),
@@ -213,7 +209,7 @@ def test_cycling_on_node_mesh_delivers_everything(mesh_nodes42):
         return absorbed.count[None], total, absorbed.items.val
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh_nodes42, in_specs=P(AXES),
             out_specs=(P(AXES), P(), P(AXES)),
         )
@@ -474,7 +470,7 @@ def test_joint_tier_cycling_delivers_everything(mesh_pods222):
         return absorbed.count[None], total, absorbed.items.val
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh_pods222, in_specs=P(axes),
             out_specs=(P(axes), P(), P(axes)),
         )

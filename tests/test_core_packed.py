@@ -17,10 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import compat
 from repro.core import ForwardConfig, enqueue, forward_work, make_queue, work_item
@@ -129,7 +126,7 @@ def _run(mesh8, cfg, dest_of):
         return nq.count[None], nq.items.pixel, nq.items.origin, nq.items.tmin
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P("data"), P("data"), P("data")),
         )
@@ -146,20 +143,14 @@ def _run(mesh8, cfg, dest_of):
 _BACKENDS = [
     pytest.param("padded", False, id="padded"),
     pytest.param("padded", True, id="padded-pallas"),
-    pytest.param(
-        "ragged", False, id="ragged",
-        marks=pytest.mark.skipif(
-            not compat.HAS_RAGGED_ALL_TO_ALL,
-            reason="installed JAX has no lax.ragged_all_to_all",
-        ),
-    ),
+    pytest.param("ragged", False, id="ragged"),
 ]
 
 
 @pytest.mark.parametrize("exchange,use_pallas", _BACKENDS)
 def test_packed_forward_bitexact_vs_onehot(mesh8, exchange, use_pallas):
-    if exchange == "ragged" and jax.default_backend() == "cpu":
-        pytest.skip("XLA:CPU cannot execute ragged_all_to_all")
+    if exchange == "ragged" and not compat.ragged_executes():
+        pytest.skip(f"the {jax.default_backend()} backend cannot execute ragged_all_to_all")
     dest_of = lambda me, k: (me * 5 + k * 3) % R
     got = _run(
         mesh8,
@@ -208,7 +199,7 @@ def test_packed_forward_multi_leaf_dtypes(mesh8):
         return nq.count[None], nq.items.tag, nq.items.mat, total
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P("data"), P("data"), P()),
         )

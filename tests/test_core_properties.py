@@ -16,13 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
-
-from repro import compat
 
 from repro.core import DISCARD, ForwardConfig, WorkQueue, forward_work, work_item
 
@@ -56,7 +51,7 @@ def _make_fn(mesh8, exchange):
         return nq.items.val, nq.items.src, nq.count[None], nq.drops[None], total
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             fwd, mesh=mesh8,
             in_specs=(P("data"), P("data"), P("data")),
             out_specs=(P("data"), P("data"), P("data"), P("data"), P()),

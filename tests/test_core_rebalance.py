@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import DISCARD, ForwardConfig, WorkQueue, rebalance, work_item
 
 R, CAP = 8, 64
@@ -46,7 +45,7 @@ def _run_rebalance(mesh, cfg, axes, count_of, dest_of, val_of, scope="global"):
         return nq.count[None], nq.items.val, nq.items.src, total
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             bal, mesh=mesh, in_specs=P(axes),
             out_specs=(P(axes), P(axes), P(axes), P()),
         )
@@ -187,7 +186,7 @@ def test_intra_scope_zero_slow_tier_payload_bytes(mesh_pods222):
         return nq.count[None], nq.items.src, total
 
     jitted = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             bal, mesh=mesh_pods222, in_specs=P(axes),
             out_specs=(P(axes), P(axes), P()),
         )
@@ -250,7 +249,7 @@ def test_intra_scope_delivers_in_group_and_holds_cross_group_pending(mesh_nodes2
         return nq.count[None], nq.items.val, nq.dest, nq.drops[None], total
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             bal, mesh=mesh_nodes24, in_specs=P(axes),
             out_specs=(P(axes), P(axes), P(axes), P(axes), P()),
         )

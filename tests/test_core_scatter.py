@@ -6,7 +6,7 @@ bit-exact placement — the scatter reproduces the §4.2.1 lexicographic stable
 source order without ever sorting.  Property-tested on flat and 2/3-level
 hierarchical meshes, including the hot-spot, the all-DISCARD round, and
 sender/receiver capacity overflow; the Pallas ``bucket_scatter`` path is
-pinned against the XLA path under the ``pallas_interpret`` CI toggle.
+pinned against the XLA path (interpret mode off the TPU).
 
 The drop-accounting regression: when ONE overflowing segment is clamped at
 MULTIPLE hierarchy tiers, every dropped item must be counted exactly once —
@@ -18,13 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import DISCARD, ForwardConfig, WorkQueue, forward_work, work_item
 
 R, CAP = 8, 64
@@ -51,7 +47,7 @@ def _make_fn(mesh, cfg, axes="data"):
         return nq.items.val, nq.items.src, nq.count[None], nq.drops[None], total
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             fwd, mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes)),
             out_specs=(P(axes), P(axes), P(axes), P(axes), P()),
@@ -168,8 +164,6 @@ def test_flat_scatter_backend_self_consistency(mesh8, exchange):
 def test_ragged_scatter_lowers_with_one_ragged_collective(mesh8):
     """The ragged backend's scatter mode must still lower to the single
     ragged_all_to_all + one count all_gather (budget unchanged)."""
-    if not compat.HAS_RAGGED_ALL_TO_ALL:
-        pytest.skip("installed JAX has no lax.ragged_all_to_all")
     from repro.roofline.analysis import collective_ops
 
     cfg = ForwardConfig("data", R, CAP, exchange="ragged", marshal="scatter")
@@ -284,7 +278,6 @@ def test_3level_scatter_degenerate_axes(shape):
 
 
 # ------------------------------------------------------------- Pallas path
-@pytest.mark.pallas_interpret
 @pytest.mark.parametrize("kind", ["flat", "hier3"])
 def test_scatter_pallas_path_matches_xla_path(mesh8, mesh_pods222, kind):
     """use_pallas=True routes the plan through kernels/bucket_scatter and the
@@ -308,10 +301,8 @@ def test_scatter_pallas_path_matches_xla_path(mesh8, mesh_pods222, kind):
 
 # ------------------------------------------------------------------ cycling
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
-def test_cycling_scatter_delivers_everything(mesh8, use_pallas, request):
+def test_cycling_scatter_delivers_everything(mesh8, use_pallas):
     """§6.3 cycling with the sort-free hop compaction delivers every item."""
-    if use_pallas:
-        request.applymarker(pytest.mark.pallas_interpret)
     from repro.core import enqueue, make_queue
     from repro.core.cycling import deliver_by_cycling
 
@@ -334,7 +325,7 @@ def test_cycling_scatter_delivers_everything(mesh8, use_pallas, request):
         return absorbed.count[None], total, absorbed.items.val
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P(), P("data")),
         )
@@ -374,7 +365,7 @@ def test_rebalance_scatter_matches_sort(mesh_pods222):
             return nq.items.val, nq.count[None], total
 
         f = jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 bal, mesh=mesh_pods222, in_specs=P(AXES3),
                 out_specs=(P(AXES3), P(AXES3), P()),
             )

@@ -6,10 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import work_item
 from repro.kernels.bucket_scatter import (
@@ -26,15 +23,18 @@ from repro.kernels.sort_keys import kernel as sk_kernel, ops as sk_ops, ref as s
 
 
 # ---------------------------------------------------------------- sort_keys
-@pytest.mark.parametrize("cap,tile", [(64, 16), (256, 256), (1024, 128), (96, 32)])
+@pytest.mark.parametrize(
+    "cap,block_rows", [(64, 8), (3000, 8), (4096, 16), (1024, 256)]
+)
 @pytest.mark.parametrize("num_ranks", [4, 8, 64])
-def test_sort_keys_pack_hist_matches_ref(cap, tile, num_ranks):
+def test_sort_keys_pack_hist_matches_ref(cap, block_rows, num_ranks):
     rng = np.random.default_rng(cap + num_ranks)
     dest = jnp.array(rng.integers(-2, num_ranks + 1, cap), jnp.int32)
     count = jnp.int32(rng.integers(0, cap + 1))
     ib = max(1, (cap - 1).bit_length())
     keys, hist = sk_kernel.pack_and_histogram(
-        dest, count, num_ranks=num_ranks, idx_bits=ib, tile=tile, interpret=True
+        dest, count, num_ranks=num_ranks, idx_bits=ib, block_rows=block_rows,
+        interpret=True,
     )
     rkeys, rhist = sk_ref.pack_and_histogram(dest, count, num_ranks=num_ranks, idx_bits=ib)
     np.testing.assert_array_equal(np.asarray(keys), np.asarray(rkeys))
@@ -67,20 +67,20 @@ def test_sort_keys_full_sort_matches_core():
 
 
 # ----------------------------------------------------------- bucket_scatter
-@pytest.mark.pallas_interpret
 @pytest.mark.parametrize(
-    "cap,tile", [(64, 16), (256, 256), (96, 32), (192, 64), (128, 128)]
+    "cap,block_rows", [(64, 8), (3000, 8), (4096, 16), (1152, 8), (1024, 256)]
 )
 @pytest.mark.parametrize("num_ranks", [4, 8, 64])
-def test_bucket_scatter_rank_hist_matches_ref(cap, tile, num_ranks):
-    """The chunked-MXU prefix kernel vs the one-hot cumsum oracle — d_clean,
-    in-bucket rank, and histogram all bit-equal (incl. non-128-multiple tiles
-    that exercise the gcd chunking)."""
+def test_bucket_scatter_rank_hist_matches_ref(cap, block_rows, num_ranks):
+    """The triangular-MXU prefix kernel vs the one-hot cumsum oracle —
+    d_clean, in-bucket rank, and histogram all bit-equal (incl. capacities
+    that are not a whole number of blocks, and multi-block grids that carry
+    the running histogram)."""
     rng = np.random.default_rng(cap + num_ranks)
     dest = jnp.array(rng.integers(-2, num_ranks + 2, cap), jnp.int32)
     count = jnp.int32(rng.integers(0, cap + 1))
     dk, rk, hk = bs_kernel.rank_and_histogram(
-        dest, count, num_ranks=num_ranks, tile=tile, interpret=True
+        dest, count, num_ranks=num_ranks, block_rows=block_rows, interpret=True
     )
     dr, rr, hr = bs_ref.rank_and_histogram(dest, count, num_ranks=num_ranks)
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
@@ -88,7 +88,6 @@ def test_bucket_scatter_rank_hist_matches_ref(cap, tile, num_ranks):
     np.testing.assert_array_equal(np.asarray(hk), np.asarray(hr))
 
 
-@pytest.mark.pallas_interpret
 @pytest.mark.parametrize("n,slots,D", [(64, 64, 3), (256, 80, 9), (100, 64, 1)])
 def test_bucket_scatter_rows_matches_ref(n, slots, D):
     """scatter_rows vs its jnp oracle, incl. out-of-range (dropped) rows and
@@ -101,7 +100,6 @@ def test_bucket_scatter_rows_matches_ref(n, slots, D):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.pallas_interpret
 def test_bucket_scatter_negative_positions_are_dropped():
     """Negative dstpos must land in the trash, not wrap to a valid slot
     (``.at[].set`` wraps negatives even with mode='drop' — the ref guards
@@ -125,7 +123,6 @@ def test_bucket_scatter_rejects_f32_inexact_capacity():
         )
 
 
-@pytest.mark.pallas_interpret
 def test_bucket_scatter_reproduces_sort_placement():
     """The tentpole equivalence at the kernel level: scattering every row to
     ``off[dest] + rank`` reproduces key-pack + lax.sort + gather bit-exactly
@@ -154,11 +151,15 @@ def test_bucket_scatter_reproduces_sort_placement():
 
 
 # ------------------------------------------------------------------ compact
-@pytest.mark.parametrize("cap,tile", [(32, 8), (512, 128), (2048, 2048), (48, 16)])
-def test_compact_positions_matches_ref(cap, tile):
+@pytest.mark.parametrize(
+    "cap,block_rows", [(32, 8), (5000, 8), (4096, 16), (2048, 256)]
+)
+def test_compact_positions_matches_ref(cap, block_rows):
     rng = np.random.default_rng(cap)
     mask = jnp.array(rng.random(cap) < 0.4)
-    pos, total = compact_ops.K.compact_positions(mask, tile=tile, interpret=True)
+    pos, total = compact_ops.K.compact_positions(
+        mask, block_rows=block_rows, interpret=True
+    )
     rpos, rtotal = compact_ref.compact_positions(mask)
     np.testing.assert_array_equal(np.asarray(pos), np.asarray(rpos))
     assert int(total[0]) == int(rtotal[0])

@@ -150,7 +150,6 @@ def test_route_layers_record_trace_time_events(mesh8):
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.core import (
         DISCARD, ForwardConfig, WorkQueue, deliver_by_cycling, rebalance,
         work_item,
@@ -177,7 +176,7 @@ def test_route_layers_record_trace_time_events(mesh8):
         return absorbed.count[None], total
 
     with OT.capture() as tr:
-        jax.jit(compat.shard_map(
+        jax.jit(jax.shard_map(
             kern, mesh=mesh8, in_specs=P("data"), out_specs=(P("data"), P()),
         )).lower(jnp.arange(8.0))
     (rb,) = tr.select(name="route.rebalance")

@@ -395,7 +395,7 @@ def test_rebalance_evacuates_unhealthy_rank(mesh8):
     from repro import compat
 
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=(P("data"), P()),
             out_specs=(P("data"), P(), P("data")),
         )

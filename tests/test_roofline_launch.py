@@ -3,13 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis — deterministic stub
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
-
-from repro import compat
 
 from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import resolve_spec
@@ -171,7 +166,7 @@ def test_rebalance_under_heavy_skew(mesh8):
 
     from jax.sharding import PartitionSpec as P
 
-    f = jax.jit(compat.shard_map(bal, mesh=mesh8, in_specs=P("data"),
+    f = jax.jit(jax.shard_map(bal, mesh=mesh8, in_specs=P("data"),
                               out_specs=(P("data"), P())))
     counts, total = f(jnp.arange(8.0))
     counts = np.asarray(counts)
@@ -180,3 +175,32 @@ def test_rebalance_under_heavy_skew(mesh8):
     assert counts.max() <= int(np.ceil(206 / 8))
     assert counts.sum() == 206
     assert counts.min() >= 206 - 7 * int(np.ceil(206 / 8))
+
+
+# ------------------------------------------------------ compilation cache
+@pytest.fixture
+def cache_config():
+    """Restore JAX's cache directory after a test sets it."""
+    old = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_defaults_to_the_checkout(tmp_path, monkeypatch, cache_config):
+    from repro.launch.cache import ENV_VAR, enable_compile_cache
+
+    monkeypatch.delenv(ENV_VAR, raising=False)
+    path = enable_compile_cache(tmp_path)
+    assert path == tmp_path.resolve() / ".jax_cache"
+    assert jax.config.jax_compilation_cache_dir == str(path)
+    # the same checkout always gives the same directory
+    assert enable_compile_cache(tmp_path) == path
+
+
+def test_compile_cache_env_var_wins(tmp_path, monkeypatch, cache_config):
+    from repro.launch.cache import ENV_VAR, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(ENV_VAR, str(tmp_path / "shared"))
+    assert enable_compile_cache(tmp_path / "checkout") == tmp_path / "shared"
+    assert jax.config.jax_compilation_cache_dir == before  # nothing set in code

@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import ForwardConfig, enqueue, forward_work, make_queue
 from repro.core import types as T
 
@@ -172,7 +171,7 @@ def _compile_padded_round(mesh8, cfg):
         return nq.count[None], total, nq.items.tmin
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             kernel, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P(), P("data")),
         )

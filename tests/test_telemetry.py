@@ -26,7 +26,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro import telemetry as TM
 from repro.core import (
     DISCARD,
@@ -68,7 +67,7 @@ def _forward_fn(mesh, cfg, axes="data"):
         return nq.count[None], nq.drops[None], TM.stack_ring(stats)
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             fwd, mesh=mesh,
             in_specs=(P(axes), P(axes)),
             out_specs=(P(axes), P(axes), _stats_specs(cfg, axes)),
@@ -322,7 +321,7 @@ def test_run_until_done_carries_ring_and_overwrites_window(mesh8):
 
     ring_proto = TM.make_ring(1, window=4, buckets=BUCKETS)
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             drive, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), jax.tree.map(lambda _: P("data"), ring_proto)),
         )
@@ -393,7 +392,7 @@ def test_cycling_records_per_hop_occupancy(mesh8):
 
     ring_proto = TM.make_ring(1, window=R, buckets=BUCKETS)
     f = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             drive, mesh=mesh8, in_specs=P("data"),
             out_specs=(P("data"), P(), jax.tree.map(lambda _: P("data"), ring_proto)),
         )
@@ -434,7 +433,7 @@ def test_rebalance_returns_stats_with_telemetry(mesh_pods222):
         sub_tiers = 3 if scope == "global" else 1
         proto = TM.make_stats(sub_tiers, BUCKETS)
         return jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 bal, mesh=mesh_pods222, in_specs=P(AXES3),
                 out_specs=(P(AXES3), P(), jax.tree.map(lambda _: P(AXES3), proto)),
             )

@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro import telemetry as TM
 from repro.core import (
     DISCARD,
@@ -188,7 +187,7 @@ def _make_run_burst(mesh, axes):
             ),
         )
         return jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 drive, mesh=mesh, in_specs=P(axes),
                 out_specs=(P(axes), ring_spec),
             )
