@@ -23,16 +23,7 @@
                          rounds over growing working sets (quoted ratio =
                          adjacent-pair median; ``gated=1`` marks the
                          cache-exceeding points the compare gate covers),
-                         plus an ungated 3-level trend point; with
-                         ``--profile`` the bulk phase breakdown feeds the
-                         overlap_efficiency_model (perfect-overlap ICI bound
-                         vs sync-fabric bound) bracketing the measured ratio.
-  fwd_profile_*          only with ``--profile``: per-phase breakdown of a
-                         padded round — marshal (plan + send-buffer build) /
-                         count collective / payload collective / unmarshal —
-                         each phase timed as its own jitted program (the sum
-                         can exceed the fused round, which runs all phases in
-                         one XLA program; the split shows WHERE time goes).
+                         plus an ungated 3-level trend point.
   rebalance_skew_*       skewed-load rebalance (flat / topology-aware /
                          intra scope) with per-tier payload bytes from the
                          lowered HLO — intra must put zero below the
@@ -128,8 +119,7 @@ BENCH_PR7.json is this gate's dump.
 round must hold a ≤1.0× walltime geomean against the bulk round on the
 ballasted flat points whose buffers exceed the cache — where the locality
 mechanism applies; pipelining exists only for walltime, so ANY regression
-there defeats it — with the phase-profile overlap model bracketing the
-measured ratio.  BENCH_PR8.json is this gate's dump.
+there defeats it.  BENCH_PR8.json is this gate's dump.
 ``--compare open,credit`` is the PR-9 gate: credit-flow walltime must stay
 within a 1.05× geomean of open flow on the fully-credited happy path, and
 the chaos_backpressure acceptance must hold (credit lossless with bounded
@@ -182,8 +172,6 @@ from _harness import (  # noqa: E402
     emit,
     record_cfg,
 )
-
-PROFILE = False  # --profile: per-phase fwd_profile_* rows (see docstring)
 
 
 # ------------------------------------------------- Fig. 8: wire efficiency
@@ -269,28 +257,6 @@ def fwd_walltime():
             us, _ = _timeit(f, jnp.arange(8.0))
             rays_s = 8 * n_emit / (us / 1e6)
             emit(f"fwd_walltime_{exchange}_n{n_emit}", us, f"rays_per_s={rays_s:.2e}")
-            if PROFILE and exchange == "padded":
-                _profile_phases(f"padded_n{n_emit}", cfg, mesh, n_emit, cap)
-
-
-def _profile_phases(tag, cfg, mesh, n_emit, cap):
-    """--profile: thin consumer of :func:`repro.obs.phases.profile_phases`
-    (PR 10 promoted the phase split into the observation law's library,
-    growing it from the flat padded four to hierarchical / pipelined /
-    ragged rounds).  Row names ``fwd_profile_{tag}_{phase}`` and the
-    ``marshal_mode=…;n_emit=…`` derived string are STABLE since PR 8; the
-    bench ``_timeit`` methodology is passed through."""
-    from repro.obs.phases import profile_phases
-
-    phase_us = profile_phases(
-        cfg, mesh, n_emit=n_emit, cap=cap, proto=_ray_proto(), timeit=_timeit
-    )
-    for phase, us in phase_us.items():
-        emit(
-            f"fwd_profile_{tag}_{phase}", us,
-            f"marshal_mode={cfg.marshal};n_emit={n_emit}",
-        )
-    return phase_us
 
 
 # ------------------------------------- ISSUE 2: hierarchical vs flat route
@@ -1377,17 +1343,13 @@ def fwd_walltime_marshal(samples=8):
                     f";marshal_total_B={model['total_bytes']:.0f}"
                     f";payload_passes={model['payload_passes']:.0f}",
                 )
-                if PROFILE and tag == "flat":
-                    _profile_phases(
-                        f"marshal_{marshal}_n{n_emit}", cfg, mesh_flat, n_emit, cap
-                    )
     return times
 
 
 PIPELINE_GATE_MIN_EMIT = 16384  # flat points at/above this gate the geomean
 
 
-def fwd_walltime_pipeline(samples=8, profile=None):
+def fwd_walltime_pipeline(samples=8):
     """Bulk-synchronous vs micro-shard pipelined forwarding (ISSUE 8): the
     flat padded round at ``pipeline_shards=4`` on compute-ballasted rounds
     (``ballast_iters=128`` — the exchange must amortize against rounds that
@@ -1396,8 +1358,8 @@ def fwd_walltime_pipeline(samples=8, profile=None):
     being the ADJACENT-PAIR median (``_pair_ratio``) — the only estimator
     stable enough for a ≤1.0× gate on a drifting host.
 
-    On this CPU backend collectives are synchronous memcpys, so the overlap
-    model's async term is 0 and the measured pipelined win is the locality
+    On this CPU backend collectives are synchronous memcpys, so no wire time
+    hides behind compute and the measured pipelined win is the locality
     corollary: each 1/S chunk is marshalled, shipped and compacted while
     still cache-resident, which starts paying once the round's buffers
     outgrow the cache.  The gate therefore covers only the flat points at
@@ -1406,21 +1368,13 @@ def fwd_walltime_pipeline(samples=8, profile=None):
     a 3-level trend point ride along UNGATED (sub-cache rounds are
     launch-overhead-bound on this fabric, and the hier route's per-tier
     chunks are S× smaller still — both rows document the CPU limitation
-    that the overlap model's ``async_fraction=1`` (TPU ICI) bound removes).
-    With ``--profile`` (always on in the gate) the bulk round's four phases
-    are timed standalone at the gate's anchor point and
-    :func:`repro.roofline.analysis.overlap_efficiency_model` brackets the
-    measured ratio between perfect overlap (a=1, the ICI target) and no
-    overlap (a=0, this fabric).  Returns ``(times, ratios)`` —
+    an async fabric such as TPU ICI removes).  Returns ``(times, ratios)`` —
     ``{(tag, variant, n_emit): median_us}`` and
     ``{(tag, n_emit): pair_ratio}`` — for the ``--compare bulk,pipelined``
     gate."""
     from repro.core import ForwardConfig
     from repro.launch.mesh import make_pod_mesh
-    from repro.roofline.analysis import overlap_efficiency_model
 
-    if profile is None:
-        profile = PROFILE
     S, ballast = 4, 128
     mesh = _mesh8()
     times, ratios = {}, {}
@@ -1451,22 +1405,6 @@ def fwd_walltime_pipeline(samples=8, profile=None):
                 f";ballast_iters={ballast}"
                 f";ratio={ratio if variant == 'pipelined' else 1.0:.3f}"
                 f";gated={int(n_emit >= PIPELINE_GATE_MIN_EMIT)}",
-            )
-        if profile and n_emit == 32768:
-            phase_us = _profile_phases(
-                f"pipeline_bulk_n{n_emit}", cfgs["bulk"], mesh, n_emit, cap
-            )
-            ici = overlap_efficiency_model(phase_us, S, async_fraction=1.0)
-            sync = overlap_efficiency_model(phase_us, S, async_fraction=0.0)
-            emit(
-                f"fwd_profile_pipeline_overlap_n{n_emit}",
-                ici["pipelined_us"],
-                f"bulk_us={ici['bulk_us']:.1f};wire_us={ici['wire_us']:.1f}"
-                f";compute_us={ici['compute_us']:.1f}"
-                f";ici_bound_ratio={ici['pipelined_us'] / ici['bulk_us']:.3f}"
-                f";sync_fabric_ratio={sync['pipelined_us'] / sync['bulk_us']:.3f}"
-                f";measured_ratio={ratio:.3f}"
-                f";ici_speedup={ici['speedup']:.3f}",
             )
     # hier3 trend point (ungated — see docstring)
     mesh_pod = make_pod_mesh(2, 2, 2)
@@ -1700,9 +1638,7 @@ def compare_backends(spec: str) -> int:
         # medians (see _pair_ratio) — per-variant medians drift by more than
         # the gate margin on this host.  The sub-cache flat point and the
         # hier3 rows are reported but not gated (see fwd_walltime_pipeline).
-        # The phase-profile overlap model must bracket the measurement: the
-        # perfect-overlap (ICI) bound is a floor no fabric can beat.
-        times, pair_ratios = fwd_walltime_pipeline(samples=40, profile=True)
+        times, pair_ratios = fwd_walltime_pipeline(samples=40)
         ratios = []
         for (tag, n_emit), ratio in sorted(pair_ratios.items()):
             us = times[(tag, "pipelined", n_emit)]
@@ -1715,19 +1651,6 @@ def compare_backends(spec: str) -> int:
                 ratios.append(ratio)
         geomean = float(np.exp(np.mean(np.log(ratios))))
         emit("compare_pipeline_geomean", 0.0, f"ratio={geomean:.3f}")
-        overlap_rows = [
-            r for r in ROWS if r["name"].startswith("fwd_profile_pipeline_overlap")
-        ]
-        for r in overlap_rows:
-            lb = float(r["derived"]["ici_bound_ratio"])
-            measured = float(r["derived"]["measured_ratio"])
-            if measured < lb - 0.05:
-                print(
-                    f"# COMPARE FAILED: measured pipelined ratio {measured:.3f} "
-                    f"beats the perfect-overlap bound {lb:.3f} — the "
-                    f"measurement or the phase model is broken"
-                )
-                return 1
         if geomean > 1.0:
             print(
                 f"# COMPARE FAILED: pipelined regresses bulk by "
@@ -1933,10 +1856,6 @@ def main(argv=None) -> None:
                     help=f"fast subset only: {', '.join(SMOKE_SECTIONS)}")
     ap.add_argument("--only", metavar="SUBSTR", default=None,
                     help="run only sections whose name contains SUBSTR")
-    ap.add_argument("--profile", action="store_true",
-                    help="per-phase breakdown (marshal / count collective / "
-                         "payload collective / unmarshal) of the padded "
-                         "fwd_walltime_* rounds, as fwd_profile_* rows")
     ap.add_argument("--autotune", action="store_true",
                     help="run only the ISSUE-5 autotune_drift section "
                          "(drifting hot-spot + adaptive capacity controller)")
@@ -1967,9 +1886,8 @@ def main(argv=None) -> None:
                          "save-free segmented drive and runs the "
                          "chaos_recovery acceptance; 'bulk,pipelined' gates "
                          "micro-shard pipelining at a 1.0x geomean over the "
-                         "bulk round on ballasted cache-exceeding rounds, "
-                         "with the phase-profile overlap model bracketing "
-                         "the measurement; 'open,credit' gates credit flow "
+                         "bulk round on ballasted cache-exceeding rounds; "
+                         "'open,credit' gates credit flow "
                          "at a 1.05x walltime geomean over open flow on the "
                          "fully-credited happy path and runs the "
                          "chaos_backpressure acceptance; 'off,obs' gates "
@@ -1981,8 +1899,6 @@ def main(argv=None) -> None:
                          "overload run as degraded)")
     args = ap.parse_args(argv)
 
-    global PROFILE
-    PROFILE = args.profile
     if args.autotune:
         args.only = "autotune_drift"
     if args.chaos:

@@ -241,12 +241,11 @@ class RafiContext:
 
         # Observation hook (host-side only — the traced program is untouched,
         # so the lowered HLO is bit-identical with tracing on or off): each
-        # burst invocation becomes one span carrying the drive's outcome.
+        # burst invocation becomes one span carrying the drive's outcome, and
+        # a ``rafi.drive.run_until_done`` annotation on any profiler trace.
         def traced_drive(*args):
             from repro.obs import trace as OT
 
-            if not OT.enabled():
-                return drive_p(*args)
             with OT.span(
                 "drive.run_until_done", OT.CAT_DRIVE,
                 exchange=cfg.exchange, flow=cfg.flow, overflow=cfg.overflow,

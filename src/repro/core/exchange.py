@@ -24,8 +24,9 @@ round pre-collective, whichever mode runs.
 Since ISSUE 8 the backends are THIN COMPOSITIONS of the stage objects in
 ``core.stages`` (SpillExtract → Marshal → CountExchange → PayloadExchange →
 Unmarshal over an explicit ``RoundState``): the marshal/clamp/spill/compact
-arithmetic lives there exactly once, shared by every backend.  The same
-layer supplies the overlap law: ``pipeline_shards=S`` splits each exchange's
+arithmetic lives there exactly once, shared by every backend, and
+``stages.compose`` runs each stage under its ``rafi.<stage>`` device scope.
+The same layer supplies the overlap law: ``pipeline_shards=S`` splits each exchange's
 per-peer slot rows into S micro-shards whose send/recv chains are issued
 interleaved (``stages.Pipelined``) — S payload + S count collectives per
 mesh axis, payload wire bytes exactly conserved, placement bit-exact with
@@ -171,49 +172,11 @@ from repro.core import stages as ST
 from repro.telemetry import stats as TS
 
 __all__ = [
-    "exchange_counts",
-    "exchange_count_matrix",
     "exchange_padded",
     "exchange_ragged",
     "exchange_hierarchical",
     "exchange_onehot",
-    "padded_send_buffer",
 ]
-
-# The shared stage-library arithmetic (ISSUE 8 moved it to ``core.stages``);
-# re-exported under the historic private names for callers that composed
-# against the monolith (benchmark phase profiles, cycling's ring hop).
-padded_send_buffer = ST.padded_send_buffer
-_a2a = ST.a2a
-_scatter = ST.scatter_rows
-_spill_positions = ST.spill_positions
-_lanes_spill = ST.lanes_spill
-_clamp_subsegments = ST.clamp_subsegments
-_subsegment_gather = ST.subsegment_gather
-_compact_blocks = ST.compact_blocks
-_ragged_control_plane = ST.ragged_control_plane
-
-
-def exchange_counts(send_counts: jax.Array, axis_name) -> jax.Array:
-    """§4.2.2 step 2 — MPI_Alltoall of per-peer counts.
-
-    ``send_counts``: (R,) — how many items *I* send to each peer.
-    Returns (R,): how many items each peer sends *me*.
-    """
-    return ST.a2a(send_counts[:, None], axis_name).reshape(-1)
-
-
-def exchange_count_matrix(send_counts: jax.Array, axis_name) -> jax.Array:
-    """All-gather the per-rank send-count vectors into the full (R, R) count
-    matrix ``M[s, d] = items s sends to d``.
-
-    One tiny collective (R² int32 — 256 KiB even at R=256) buys the ENTIRE
-    ragged control plane: every rank derives every rank's receive layout,
-    capacity clamps, and landing offsets locally, so no chained count
-    exchanges are needed before the payload collective.
-    """
-    return jax.lax.all_gather(send_counts, axis_name)
-
 
 def exchange_padded(
     packed: jax.Array,  # (C, W) uint32 — UNSORTED packed payload
@@ -429,7 +392,7 @@ def exchange_hierarchical(
         retain=retain, age=age, flow=flow, credits=credits,
     )
     if credit:
-        st = ST.CreditGate(flat_axes, R)(st)
+        st = ST.compose(ST.CreditGate(flat_axes, R))(st)
     st.spill_run = jnp.zeros((), send_counts.dtype)  # total rows parked so far
     st.drops = jnp.zeros((), send_counts.dtype)
     if retain:
@@ -496,8 +459,8 @@ def exchange_hierarchical(
         stride = 1
         for sz in level_sizes[l + 1:]:
             stride *= sz
-        st = ST.SpillExtract(
-            R, capacity, S, retain=retain, kind="tier", extent=A
+        st = ST.compose(
+            ST.SpillExtract(R, capacity, S, retain=retain, kind="tier", extent=A)
         )(st)
         if telemetry:
             # segment demand at tier l = pre-clamp rows per peer slot column
@@ -536,9 +499,8 @@ def exchange_hierarchical(
                 ST.Unmarshal(capacity, shards=pipeline_shards, slot=S, kind="final"),
             )
             if pipeline_shards > 1:
-                st = ST.Pipelined(chain, pipeline_shards)(st)
-            else:
-                st = ST.compose(*chain)(st)
+                chain = (ST.Pipelined(chain, pipeline_shards),)
+            st = ST.compose(*chain)(st)
             total_drops = st.drops + st.recv_drops
             if telemetry:
                 # wasted wire = every row discarded AFTER crossing a wire:
@@ -586,11 +548,10 @@ def exchange_hierarchical(
             ST.PayloadExchange(axis_name[l], collect=pipeline_shards > 1),
         )
         if pipeline_shards > 1:
-            st = ST.Pipelined(chain, pipeline_shards)(st)
-            st = ST.Reassemble(A, S)(st)
-        else:
-            st = ST.compose(*chain)(st)
-        st = ST.AdvanceTier(A, S, axis_name[l], retain=retain, num_ranks=R)(st)
+            chain = (ST.Pipelined(chain, pipeline_shards), ST.Reassemble(A, S))
+        st = ST.compose(
+            *chain, ST.AdvanceTier(A, S, axis_name[l], retain=retain, num_ranks=R)
+        )(st)
 
 
 def exchange_ragged(
@@ -623,7 +584,8 @@ def exchange_ragged(
     collective; the receive side is written compacted directly (no unpack
     pass), which is the paper's "large contiguous blocks at very high
     bandwidth" property.  The control plane is one all-gather of the
-    send-count vector (see :func:`exchange_count_matrix`).  With
+    send-count vector (``stages.CountExchange(kind="ragged")``): every rank
+    derives every clamp and landing offset from the (R, R) count matrix.  With
     ``overflow="retain"`` the rows past each segment's control-plane
     allowance (``send_sizes``) come back as a pending spill block instead
     of being dropped — the shipped segments are unchanged.
@@ -644,110 +606,38 @@ def exchange_ragged(
     room from last round), and this rank's fresh advert replaces its own
     entry in the returned ``credits_out`` — every rank's estimate of every
     receiver refreshes every round with no payload-sized traffic added.
+
+    Stages: ``[CreditGate →] CountExchange → SpillExtract → Marshal →
+    PayloadExchange → Unmarshal``, all ``kind="ragged"`` (the count and
+    payload collectives pipelined per shard).
     """
     del peer_capacity  # segments are contiguous: no slot gather
     retain = overflow == "retain"
     credit = flow == "credit"
     R = num_ranks
-    me = jax.lax.axis_index(axis_name)
-    off = jnp.cumsum(send_counts) - send_counts
-
-    credits_out = grant = None
-    send_gated = send_counts
-    if credit:
-        free = jnp.clip(credits, 0)
-        grant = (free // R + (me < free % R)).astype(send_counts.dtype)
-        send_gated = jnp.minimum(send_counts, grant)
-        # shard 0's count collective, widened by this rank's own-entry advert
-        wide = jnp.concatenate(
-            [send_gated, jnp.take(credits, me)[None].astype(send_gated.dtype)]
-        )
-        gath = jax.lax.all_gather(wide, axis_name)  # (R, R+1)
-        cnt, credits_out = gath[:, :R], gath[:, R].astype(jnp.int32)
-    else:
-        cnt = exchange_count_matrix(send_counts, axis_name)  # shard 0's count collective
-    send_sizes, output_offsets, recv_sizes = ST.ragged_control_plane(
-        cnt, me, capacity
+    st = ST.RoundState(
+        packed=packed, perm=perm, send_counts=send_counts, marshal=marshal,
+        dest_clean=dest_clean, dest_rank=dest_rank, use_pallas=use_pallas,
+        retain=retain, age=age, flow=flow, credits=credits,
     )
-    send_drops = jnp.sum(send_counts - send_sizes)
-    front = None
-    if retain:
-        # Segment-tail spill extraction, exactly as exchange_padded — the
-        # allowance here is the control plane's ``send_sizes``.
-        if age is None:
-            age = jnp.zeros((packed.shape[0],), jnp.int32)
-        pending = (ST.lanes_spill(
-            packed, perm, age, send_sizes, send_counts - send_sizes,
-            off + send_sizes, send_drops, num_ranks=num_ranks,
-            marshal=marshal, dest_clean=dest_clean, dest_rank=dest_rank,
-        ),)
-        front = jnp.minimum(send_drops, capacity)
-        held_rows = send_drops
-        if credit:
-            # fresh advert: the room left behind the reserved spill front,
-            # minus the reserve withheld for next round's local emissions,
-            # floored at one row per sender whenever room exists (liveness
-            # — see stages.SpillExtract's flat advert)
-            room = capacity - front
-            credits_out = credits_out.at[me].set(
-                jnp.maximum(
-                    jnp.clip(room - credit_reserve, 0),
-                    jnp.minimum(room, num_ranks),
-                ).astype(jnp.int32)
-            )
-        send_drops = jnp.zeros_like(send_drops)
-
-    if marshal == "scatter":  # the ONE payload pass, sort-free
-        keep = dest_clean < num_ranks
-        pos = off[jnp.clip(dest_clean, 0, num_ranks - 1)] + dest_rank
-        dstpos = jnp.where(keep, pos, packed.shape[0])
-        sorted_packed = ST.scatter_rows(
-            packed, dstpos, packed.shape[0], use_pallas=use_pallas
-        )
-    else:
-        sorted_packed = jnp.take(packed, perm, axis=0)  # the ONE payload permute
-    out = jnp.zeros((capacity, packed.shape[1]), packed.dtype)
-    if pipeline_shards == 1:
-        out = jax.lax.ragged_all_to_all(  # the ONE payload collective
-            sorted_packed,
-            out,
-            input_offsets=off,
-            send_sizes=send_sizes,
-            output_offsets=output_offsets,
-            recv_sizes=recv_sizes,
-            axis_name=axis_name,
-        )
-    else:
-        chunk = capacity // pipeline_shards
-        for k in range(pipeline_shards):
-            if k > 0:
-                # shard k's own count collective + replicated control plane
-                cnt_k = exchange_count_matrix(send_gated, axis_name)
-                s_ss, s_oo, s_rs = ST.ragged_control_plane(cnt_k, me, capacity)
-            else:
-                s_ss, s_oo, s_rs = send_sizes, output_offsets, recv_sizes
-            out = jax.lax.ragged_all_to_all(  # shard k's payload collective
-                sorted_packed,
-                out,
-                input_offsets=off + jnp.minimum(k * chunk, s_ss),
-                send_sizes=jnp.clip(s_ss - k * chunk, 0, chunk),
-                output_offsets=s_oo + jnp.minimum(k * chunk, s_ss),
-                recv_sizes=jnp.clip(s_rs - k * chunk, 0, chunk),
-                axis_name=axis_name,
-            )
-    new_count = jnp.sum(recv_sizes)
-    recv_cut = jnp.zeros((), send_counts.dtype)
-    if retain:
-        # The collective's landing offsets are fixed by the replicated
-        # control plane, so the spill front is opened AFTER the exchange by
-        # one local gather (this backend is lower-only on CPU, so the extra
-        # pass is off the walltime gate); arrivals pushed past capacity are
-        # the receiver-admission loss.
-        lane = jnp.arange(capacity, dtype=jnp.int32)
-        out = jnp.take(out, jnp.clip(lane - front, 0, capacity - 1), axis=0)
-        admitted = jnp.minimum(new_count, capacity - front)
-        recv_cut = new_count - admitted
-        new_count = admitted
+    st.base = jnp.cumsum(send_counts) - send_counts  # segment starts, sorted order
+    count = ST.CountExchange(axis_name, kind="ragged", num_ranks=R, capacity=capacity)
+    payload = ST.PayloadExchange(
+        axis_name, kind="ragged", capacity=capacity, shards=pipeline_shards
+    )
+    wire = (ST.Pipelined((count, payload), pipeline_shards),) if pipeline_shards > 1 else (payload,)
+    head = (ST.CreditGate(axis_name, R),) if credit else ()
+    st = ST.compose(
+        *head,
+        count,
+        ST.SpillExtract(R, capacity, 0, retain=retain, kind="ragged",
+                        reserve=credit_reserve, axis_name=axis_name),
+        ST.Marshal(R, 0, kind="ragged"),
+        *wire,
+        ST.Unmarshal(capacity, kind="ragged"),
+    )(st)
+    drops = st.send_drops + st.recv_drops
+    pending = tuple(st.pending)
     if telemetry:
         # No per-peer slots here — the §3.3 clamp is the receiver queue, so
         # segment demand = the count matrix's per-destination column totals
@@ -756,30 +646,30 @@ def exchange_ragged(
         # population semantics).  Senders own the drop accounting on this
         # backend (each counts what the control plane cut from its row), so
         # recv_drops stays 0 — stats sum to the exchange's drops return.
-        col_demand = jnp.sum(cnt, axis=0)
+        me = jax.lax.axis_index(axis_name)
+        col_demand = jnp.sum(st.cnt, axis=0)
         tkw = {}
         if retain:
-            tkw["rows_held"] = held_rows
+            tkw["rows_held"] = st.stage_held
         if credit:
-            tkw["credits_granted"] = jnp.sum(jnp.minimum(grant, send_counts))
+            tkw["credits_granted"] = jnp.sum(jnp.minimum(st.credit_allow, send_counts))
         stats = TS.single_tier_stats(
             col_demand, capacity, telemetry_buckets,
-            sent_rows=jnp.sum(send_sizes), stage_drops=send_drops,
-            recv_total=col_demand[me], recv_drops=recv_cut.astype(jnp.int32),
+            sent_rows=jnp.sum(st.send_sizes), stage_drops=st.send_drops,
+            recv_total=col_demand[me], recv_drops=st.recv_drops.astype(jnp.int32),
             **tkw,
         )
         if credit:
-            return (out, recv_sizes, new_count, send_drops + recv_cut,
-                    pending, credits_out, stats)
+            return (st.out, st.recv_counts, st.new_count, drops, pending,
+                    st.credits_out, stats)
         if retain:
-            return out, recv_sizes, new_count, send_drops + recv_cut, pending, stats
-        return out, recv_sizes, new_count, send_drops, stats
+            return st.out, st.recv_counts, st.new_count, drops, pending, stats
+        return st.out, st.recv_counts, st.new_count, drops, stats
     if credit:
-        return (out, recv_sizes, new_count, send_drops + recv_cut,
-                pending, credits_out)
+        return st.out, st.recv_counts, st.new_count, drops, pending, st.credits_out
     if retain:
-        return out, recv_sizes, new_count, send_drops + recv_cut, pending
-    return out, recv_sizes, new_count, send_drops
+        return st.out, st.recv_counts, st.new_count, drops, pending
+    return st.out, st.recv_counts, st.new_count, drops
 
 
 def exchange_onehot(
@@ -804,7 +694,8 @@ def exchange_onehot(
     """All-gather reference oracle (tests only): every rank sees everything,
     selects what is addressed to it, and compacts stably by (source, lane).
     Deliberately a different code path from the production backends (in
-    scatter mode only the initial into-destination-order placement differs).
+    scatter mode only the initial into-destination-order placement differs):
+    it runs no stage objects, so its ops carry ``rafi.forward`` only.
     With ``overflow="retain"`` the pending spill plan is empty by
     construction — there is no sender clamp to spill from; the receiver
     clamp stays a counted drop (there is no bounded place left to keep those

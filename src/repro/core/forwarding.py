@@ -33,6 +33,11 @@ synchronises with the host every round to read back segment offsets).  And
 where the original issues one RDMA per peer, the packed wire format means
 the whole round is one collective regardless of how many leaves the item
 type has.
+
+Device scopes: the round runs under ``rafi.forward``; the marshal plan under
+``rafi.plan``, each exchange stage under its own (``core.stages``), the
+retain merge under ``rafi.merge`` and the in-flight ``psum`` under
+``rafi.termination``.
 """
 from __future__ import annotations
 
@@ -406,49 +411,12 @@ def credit_reserve_rows(cfg: ForwardConfig) -> int:
     return cfg.capacity // 2 if cfg.emit_reserve < 0 else cfg.emit_reserve
 
 
-def forward_work(
-    q: WorkQueue, cfg: ForwardConfig, *, age=None, health=None, credits=None
-):
-    """One collective forwarding round. Must run inside ``shard_map``.
-
-    Returns ``(new_queue, total_in_flight)`` where ``total_in_flight`` is the
-    paper's §4.2.3 global reduce — the number of items alive across *all*
-    ranks after the exchange, used for distributed-termination detection.
-    With ``cfg.telemetry`` the round's ``RoundStats`` snapshot rides along as
-    a third output (``(new_queue, total, stats)``) — the arity is static in
-    the config, so traced callers thread it without cost.
-
-    With ``cfg.overflow == "retain"`` the returns become
-    ``(new_queue, total, age_out[, stats])``: clamp-cut rows come back
-    compacted to the FRONT of ``new_queue`` with their ``dest`` intact
-    (arrivals fill in behind, dest reset to DISCARD as usual), ``total``
-    counts retained rows so termination can't fire with spilled work, and
-    ``age_out`` is the per-lane rounds-waiting counter (feed it back via
-    ``age=`` on the next call; ``None`` means all lanes are fresh).  Arrivals
-    that don't fit next to the retained rows are the one remaining loss site
-    — counted into ``drops``.
-
-    With ``cfg.flow == "credit"`` the returns grow ``credits_out`` after
-    ``age_out`` (``(new_queue, total, age_out, credits_out[, stats])``):
-    ``credits_out[d]`` is destination ``d``'s free-space advertisement
-    received on this round's count collective, to be fed back via
-    ``credits=`` on the next call so the sender clamp spends wire only on
-    admissible rows.  ``credits=None`` means every receiver starts fully
-    credited (``capacity`` each) — the uncontended single-shot assumption
-    (benchmarks, examples).  The termination drive instead cold-starts its
-    carried credits at ZERO — the first round is advert-only, so no wire is
-    risked before any receiver has spoken (see ``drive_start``).
-
-    ``health`` (optional ``(R,) bool``, replicated) drains sick ranks: every
-    destination on an unhealthy rank is re-addressed pre-marshal through the
-    pure local ``core.health.remap_dest`` law, so unhealthy ranks receive
-    nothing while the collective inventory stays bit-identical to the plain
-    round (retained rows keep the REMAPPED destination — once re-addressed,
-    a row stays re-addressed).  ``None`` and an all-healthy mask are
-    bit-identical.
-    """
+@jax.named_scope("rafi.plan")
+def _plan(q: WorkQueue, cfg: ForwardConfig, health):
+    """The marshal plan of one round: the health remap, the destination sort
+    or ``destination_rank`` with its histogram, and the packed payload.
+    Returns ``(q, perm, dest_clean, dest_rank, send_counts, packed, spec)``."""
     R = cfg.num_ranks
-    retain = cfg.overflow == "retain"
     if health is not None:
         q = dataclasses.replace(q, dest=remap_dest(q.dest, health))
     perm = dest_clean = dest_rank = None
@@ -501,6 +469,117 @@ def forward_work(
         del sorted_dest
 
     packed, spec = T.pack_payload(q.items)  # (C, W) uint32 — the wire format
+    return q, perm, dest_clean, dest_rank, send_counts, packed, spec
+
+
+@jax.named_scope("rafi.merge")
+def _merge_spill(pending, recv_packed, C: int):
+    """Select the exchange's spill blocks into the front of the next queue,
+    arrivals behind them.  Returns ``(merged, dest_out, age_out, ret_count,
+    spill_over)``."""
+    lane = jnp.arange(C, dtype=jnp.int32)
+    run = jnp.zeros((), jnp.int32)
+    for entry in pending:
+        run = run + entry[-1].astype(jnp.int32)
+    ret_count = jnp.minimum(run, C)
+    spill_over = run - ret_count
+
+    if len(pending) == 1:
+        # Flat exchanges: one block at offset 0 — a single select, no
+        # index arithmetic at all.
+        rows_e, dest_e, age_e, n_e = pending[0]
+        sel = lane < n_e
+        merged = jnp.where(sel[:, None], rows_e, recv_packed)
+        dest_out = jnp.where(sel, dest_e, DISCARD)
+        age_out = jnp.where(sel, age_e, 0)
+    else:
+        # Multi-stage routes: index into the VIRTUAL concatenation
+        # [block_0 | block_1 | … | arrivals] with one payload gather
+        # instead of a per-block gather+select chain — the lane→source
+        # map is all (C,) integer math, so the payload-scale op count
+        # stays flat in the number of stages.
+        sizes = [r.shape[0] for r, _, _, _ in pending]
+        src = lane + sum(sizes)  # default: the arrivals region
+        start = jnp.zeros((), jnp.int32)
+        off = 0
+        for (rows_e, _, _, n_e), sz in zip(pending, sizes):
+            sel = (lane >= start) & (lane < start + n_e)
+            src = jnp.where(sel, off + lane - start, src)
+            start = start + n_e.astype(jnp.int32)
+            off += sz
+        merged = jnp.take(
+            jnp.concatenate([r for r, _, _, _ in pending] + [recv_packed]),
+            src,
+            axis=0,
+        )
+        dest_out = jnp.take(
+            jnp.concatenate(
+                [d for _, d, _, _ in pending]
+                + [jnp.full((C,), DISCARD, jnp.int32)]
+            ),
+            src,
+        )
+        age_out = jnp.take(
+            jnp.concatenate(
+                [a for _, _, a, _ in pending] + [jnp.zeros((C,), jnp.int32)]
+            ),
+            src,
+        )
+    return merged, dest_out, age_out, ret_count, spill_over
+
+
+@jax.named_scope("rafi.termination")
+def _global_in_flight(q: WorkQueue, cfg: ForwardConfig):
+    # §4.2.3: "a final MPI reduce-add on the number of rays received" — the
+    # global in-flight total for distributed termination.
+    return jax.lax.psum(q.count, flatten_axis_names(cfg.axis_name))
+
+
+@jax.named_scope("rafi.forward")
+def forward_work(
+    q: WorkQueue, cfg: ForwardConfig, *, age=None, health=None, credits=None
+):
+    """One collective forwarding round. Must run inside ``shard_map``.
+
+    Returns ``(new_queue, total_in_flight)`` where ``total_in_flight`` is the
+    paper's §4.2.3 global reduce — the number of items alive across *all*
+    ranks after the exchange, used for distributed-termination detection.
+    With ``cfg.telemetry`` the round's ``RoundStats`` snapshot rides along as
+    a third output (``(new_queue, total, stats)``) — the arity is static in
+    the config, so traced callers thread it without cost.
+
+    With ``cfg.overflow == "retain"`` the returns become
+    ``(new_queue, total, age_out[, stats])``: clamp-cut rows come back
+    compacted to the FRONT of ``new_queue`` with their ``dest`` intact
+    (arrivals fill in behind, dest reset to DISCARD as usual), ``total``
+    counts retained rows so termination can't fire with spilled work, and
+    ``age_out`` is the per-lane rounds-waiting counter (feed it back via
+    ``age=`` on the next call; ``None`` means all lanes are fresh).  Arrivals
+    that don't fit next to the retained rows are the one remaining loss site
+    — counted into ``drops``.
+
+    With ``cfg.flow == "credit"`` the returns grow ``credits_out`` after
+    ``age_out`` (``(new_queue, total, age_out, credits_out[, stats])``):
+    ``credits_out[d]`` is destination ``d``'s free-space advertisement
+    received on this round's count collective, to be fed back via
+    ``credits=`` on the next call so the sender clamp spends wire only on
+    admissible rows.  ``credits=None`` means every receiver starts fully
+    credited (``capacity`` each) — the uncontended single-shot assumption
+    (benchmarks, examples).  The termination drive instead cold-starts its
+    carried credits at ZERO — the first round is advert-only, so no wire is
+    risked before any receiver has spoken (see ``drive_start``).
+
+    ``health`` (optional ``(R,) bool``, replicated) drains sick ranks: every
+    destination on an unhealthy rank is re-addressed pre-marshal through the
+    pure local ``core.health.remap_dest`` law, so unhealthy ranks receive
+    nothing while the collective inventory stays bit-identical to the plain
+    round (retained rows keep the REMAPPED destination — once re-addressed,
+    a row stays re-addressed).  ``None`` and an all-healthy mask are
+    bit-identical.
+    """
+    R = cfg.num_ranks
+    retain = cfg.overflow == "retain"
+    q, perm, dest_clean, dest_rank, send_counts, packed, spec = _plan(q, cfg, health)
 
     kwargs = dict(
         axis_name=cfg.axis_name,
@@ -567,62 +646,16 @@ def forward_work(
         # to the spill were counted by the exchange; a spill past C
         # (unreachable when capacity bounds the resident population) is
         # counted here as spill_over.
-        C = cfg.capacity
-        lane = jnp.arange(C, dtype=jnp.int32)
-        run = jnp.zeros((), jnp.int32)
-        for entry in pending:
-            run = run + entry[-1].astype(jnp.int32)
-        ret_count = jnp.minimum(run, C)
-        spill_over = run - ret_count
-
-        if len(pending) == 1:
-            # Flat exchanges: one block at offset 0 — a single select, no
-            # index arithmetic at all.
-            rows_e, dest_e, age_e, n_e = pending[0]
-            sel = lane < n_e
-            merged = jnp.where(sel[:, None], rows_e, recv_packed)
-            dest_out = jnp.where(sel, dest_e, DISCARD)
-            age_out = jnp.where(sel, age_e, 0)
-        else:
-            # Multi-stage routes: index into the VIRTUAL concatenation
-            # [block_0 | block_1 | … | arrivals] with one payload gather
-            # instead of a per-block gather+select chain — the lane→source
-            # map is all (C,) integer math, so the payload-scale op count
-            # stays flat in the number of stages.
-            sizes = [r.shape[0] for r, _, _, _ in pending]
-            src = lane + sum(sizes)  # default: the arrivals region
-            start = jnp.zeros((), jnp.int32)
-            off = 0
-            for (rows_e, _, _, n_e), sz in zip(pending, sizes):
-                sel = (lane >= start) & (lane < start + n_e)
-                src = jnp.where(sel, off + lane - start, src)
-                start = start + n_e.astype(jnp.int32)
-                off += sz
-            merged = jnp.take(
-                jnp.concatenate([r for r, _, _, _ in pending] + [recv_packed]),
-                src,
-                axis=0,
-            )
-            dest_out = jnp.take(
-                jnp.concatenate(
-                    [d for _, d, _, _ in pending]
-                    + [jnp.full((C,), DISCARD, jnp.int32)]
-                ),
-                src,
-            )
-            age_out = jnp.take(
-                jnp.concatenate(
-                    [a for _, _, a, _ in pending] + [jnp.zeros((C,), jnp.int32)]
-                ),
-                src,
-            )
+        merged, dest_out, age_out, ret_count, spill_over = _merge_spill(
+            pending, recv_packed, cfg.capacity
+        )
         new_q = WorkQueue(
             items=T.unpack_payload(merged, spec),
             dest=dest_out,
             count=(ret_count + new_count).astype(jnp.int32),
             drops=q.drops + drops.astype(jnp.int32) + spill_over,
         )
-        total = jax.lax.psum(new_q.count, flatten_axis_names(cfg.axis_name))
+        total = _global_in_flight(new_q, cfg)
         if cfg.telemetry:
             stats = dataclasses.replace(
                 stats,
@@ -642,9 +675,7 @@ def forward_work(
         count=new_count.astype(jnp.int32),
         drops=q.drops + drops.astype(jnp.int32),
     )
-    # §4.2.3: "a final MPI reduce-add on the number of rays received" —
-    # the global in-flight total for distributed termination.
-    total = jax.lax.psum(new_q.count, flatten_axis_names(cfg.axis_name))
+    total = _global_in_flight(new_q, cfg)
     if cfg.telemetry:
         return new_q, total, stats
     return new_q, total

@@ -55,6 +55,7 @@ class WorkQueue:
         return jax.tree.leaves(self.items)[0].shape[0]
 
 
+@jax.named_scope("rafi.enqueue")
 def make_queue(proto, capacity: int) -> WorkQueue:
     """An empty queue for items shaped like ``proto`` (a single-item pytree).
 
@@ -87,6 +88,7 @@ def get_incoming(q: WorkQueue, i) -> Any:
     return jax.tree.map(lambda a: a[i], q.items)
 
 
+@jax.named_scope("rafi.enqueue")
 def enqueue(q: WorkQueue, items, dest, mask, *, num_ranks: int = None) -> WorkQueue:
     """Paper's ``DeviceInterface::emitOutgoing(ray, dest)``, vectorised.
 

@@ -231,7 +231,8 @@ def _drive_loop(
                     before=prev_health, after=cur,
                 )
             prev_health = cur
-        carry = segment_p(carry, np.int32(seg_end), mask)
+        with OT.span("recovery.segment", OT.CAT_RECOVERY, round=rnd, seg_end=seg_end):
+            carry = segment_p(carry, np.int32(seg_end), mask)
 
 
 def run_checkpointed(

@@ -21,7 +21,12 @@ objects over an explicit :class:`RoundState`, and the backends are thin
 compositions (``compose`` for bulk-synchronous, :class:`Pipelined` for
 micro-sharded).  The hierarchical route runs one
 SpillExtract→Marshal→CountExchange→PayloadExchange sequence per mesh axis
-(``kind="tier"``), advancing the sub-segment bookkeeping between tiers.
+(``kind="tier"``), advancing the sub-segment bookkeeping between tiers; the
+ragged route runs the same five over contiguous segments
+(``kind="ragged"``).  ``compose`` and :class:`Pipelined` run every stage
+under its device scope, ``rafi.<stage>`` from the class name, and
+:class:`Marshal` declares the rows its send buffers hold
+(``rafi_payload_rows_per_forward`` in ``repro.obs.metrics``).
 
 Micro-shard pipelining (the overlap law, ISSUE 8): with
 ``ForwardConfig(pipeline_shards=S)`` every shard-aware stage also exposes
@@ -52,10 +57,14 @@ the PR-4/PR-6 exact drop-count tests.
 from __future__ import annotations
 
 import dataclasses
+import math
+import re
 from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs import metrics as OM
 
 __all__ = [
     "RoundState",
@@ -433,10 +442,18 @@ class RoundState:
 
     # exchange working set (Marshal / CountExchange / PayloadExchange)
     send_buf: Any = None
+    payload_rows: int = 0  # rows the send buffers built so far hold (static)
     recv_counts: Any = None
     recv_buf: Any = None
     rcv: Any = None  # tier count exchange: (A, G) per-sub-segment survivors
     recv_blocks: List[Any] = dataclasses.field(default_factory=list)
+
+    # ragged control plane (CountExchange kind="ragged"; recv_counts holds
+    # the receive sizes): what each peer lets me deliver, where my segment
+    # lands on it, and the current micro-shard's (sizes, offsets, recv)
+    send_sizes: Any = None
+    out_offsets: Any = None
+    shard_plane: Any = None
 
     # results (Unmarshal)
     out: Any = None
@@ -495,10 +512,13 @@ class SpillExtract:
     kind: str = "flat"
     extent: int = 0  # tier: A_l, the stage's axis size
     reserve: int = 0  # credit: receive room withheld for local emissions
+    axis_name: Any = None  # ragged credit: the mesh axis (this rank's index)
 
     def __call__(self, st: RoundState) -> RoundState:
         if self.kind == "tier":
             return self._tier(st)
+        if self.kind == "ragged":
+            return self._ragged(st)
         S = self.slot
         st.clamped = jnp.minimum(st.send_counts, S)
         if st.flow == "credit":
@@ -546,6 +566,37 @@ class SpillExtract:
                     jnp.clip(room - self.reserve, 0),
                     jnp.minimum(room, self.num_ranks),
                 ).astype(jnp.int32)
+            send_drops = jnp.zeros_like(send_drops)
+        st.send_drops = send_drops
+        return st
+
+    def _ragged(self, st: RoundState) -> RoundState:
+        # the clamp is the replicated control plane's allowance
+        # (``send_sizes``), already tightened by any credit grant
+        send_drops = jnp.sum(st.send_counts - st.send_sizes)
+        if self.retain:
+            if st.age is None:
+                st.age = jnp.zeros((st.packed.shape[0],), jnp.int32)
+            st.pending.append(lanes_spill(
+                st.packed, st.perm, st.age, st.send_sizes,
+                st.send_counts - st.send_sizes, st.base + st.send_sizes,
+                send_drops, num_ranks=self.num_ranks, marshal=st.marshal,
+                dest_clean=st.dest_clean, dest_rank=st.dest_rank,
+            ))
+            st.front = jnp.minimum(send_drops, self.capacity)
+            st.stage_held = send_drops
+            if st.flow == "credit":
+                # fresh advert: the room left behind the reserved spill
+                # front, minus the reserve, floored at one row per sender
+                # whenever room exists (liveness — see the flat advert)
+                me = jax.lax.axis_index(self.axis_name)
+                room = self.capacity - st.front
+                st.credits_out = st.credits_out.at[me].set(
+                    jnp.maximum(
+                        jnp.clip(room - self.reserve, 0),
+                        jnp.minimum(room, self.num_ranks),
+                    ).astype(jnp.int32)
+                )
             send_drops = jnp.zeros_like(send_drops)
         st.send_drops = send_drops
         return st
@@ -603,6 +654,18 @@ class SpillExtract:
         return st
 
 
+def _declare_rows(st: RoundState) -> RoundState:
+    """Count the rows of the send buffer just built into the round's payload
+    pass and declare the running total as ``rafi_payload_rows_per_forward``
+    (the last stage of the last program traced leaves the whole round's)."""
+    st.payload_rows += math.prod(st.send_buf.shape[:-1])
+    OM.REGISTRY.set_gauge(
+        OM.PAYLOAD_ROWS, st.payload_rows,
+        "rows one rank's payload pass moves per forward, all stages",
+    )
+    return st
+
+
 @dataclasses.dataclass(frozen=True)
 class Marshal:
     """The send-side payload pass.  ``kind="flat"``: the padded (R, S, W)
@@ -610,7 +673,9 @@ class Marshal:
     layout — sort permutation composed into the first stage's gather, or the
     sort-free scatter straight into sub-segment slots; later stages gather
     from the received buffer.  ``.shard(st, k)`` builds only slot rows
-    ``[k·chunk, (k+1)·chunk)`` of every segment."""
+    ``[k·chunk, (k+1)·chunk)`` of every segment.  ``kind="ragged"``: the
+    (C, W) payload in contiguous destination segments (not sharded: only
+    the wire movement is)."""
 
     num_peers: int  # flat: R ranks; tier: A_l, the stage's axis size
     slot: int
@@ -620,26 +685,42 @@ class Marshal:
 
     def __call__(self, st: RoundState) -> RoundState:
         if self.kind == "tier":
-            return self._tier(st, None)
-        st.send_buf = padded_send_buffer(
-            st.packed, st.perm, st.send_counts,
-            num_ranks=self.num_peers, peer_capacity=self.slot,
-            use_pallas=st.use_pallas, marshal=st.marshal,
-            dest_clean=st.dest_clean, dest_rank=st.dest_rank,
-        )
-        return st
+            st = self._tier(st, None)
+        elif self.kind == "ragged":
+            st.send_buf = self._ragged(st)
+        else:
+            st.send_buf = padded_send_buffer(
+                st.packed, st.perm, st.send_counts,
+                num_ranks=self.num_peers, peer_capacity=self.slot,
+                use_pallas=st.use_pallas, marshal=st.marshal,
+                dest_clean=st.dest_clean, dest_rank=st.dest_rank,
+            )
+        return _declare_rows(st)
 
     def shard(self, st: RoundState, k: int) -> RoundState:
         if self.kind == "tier":
-            return self._tier(st, k)
-        st.send_buf = padded_send_shard(
-            st.packed, st.perm, st.send_counts,
-            num_ranks=self.num_peers, peer_capacity=self.slot,
-            shards=self.shards, k=k, use_pallas=st.use_pallas,
-            marshal=st.marshal, dest_clean=st.dest_clean,
-            dest_rank=st.dest_rank,
-        )
-        return st
+            st = self._tier(st, k)
+        else:
+            st.send_buf = padded_send_shard(
+                st.packed, st.perm, st.send_counts,
+                num_ranks=self.num_peers, peer_capacity=self.slot,
+                shards=self.shards, k=k, use_pallas=st.use_pallas,
+                marshal=st.marshal, dest_clean=st.dest_clean,
+                dest_rank=st.dest_rank,
+            )
+        return _declare_rows(st)
+
+    def _ragged(self, st: RoundState) -> jax.Array:
+        # contiguous per-destination segments: the (C, W) payload placed once
+        # into destination order — a sort-free scatter to ``base[dest] +
+        # rank``, or the gather through the sort permutation
+        C = st.packed.shape[0]
+        if st.marshal == "scatter":
+            keep = st.dest_clean < self.num_peers
+            pos = st.base[jnp.clip(st.dest_clean, 0, self.num_peers - 1)] + st.dest_rank
+            dstpos = jnp.where(keep, pos, C)
+            return scatter_rows(st.packed, dstpos, C, use_pallas=st.use_pallas)
+        return jnp.take(st.packed, st.perm, axis=0)
 
     def _gather(self, st, buf, rows, n_slots, slot):
         W = buf.shape[-1]
@@ -693,7 +774,9 @@ class Marshal:
 
 @dataclasses.dataclass(frozen=True)
 class CountExchange:
-    """The control-plane collective.  ``kind="flat"``: all_to_all of the
+    """The control-plane collective.  ``kind="ragged"``: all_gather of the
+    (R,) send counts into the full count matrix, from which every rank
+    derives the ragged control plane.  ``kind="flat"``: all_to_all of the
     clamped per-peer counts.  ``kind="tier"``: all_to_all of the per-sub-
     segment survivor counts (so the receiver can address every sub-segment
     of each incoming block).  ``kind="final"``: per-source-group totals —
@@ -729,6 +812,8 @@ class CountExchange:
     reserve: int = 0  # credit: receive room withheld for local emissions
 
     def __call__(self, st: RoundState) -> RoundState:
+        if self.kind == "ragged":
+            return self._ragged(st)
         if self.kind == "tier":
             if st.flow == "credit":
                 st.rcv = self._credit_recv(st, st.allowed.T)
@@ -751,6 +836,27 @@ class CountExchange:
                 st.credits_out = recv[:, 1]
             else:
                 st.recv_counts = a2a(st.clamped[:, None], self.axis_name).reshape(-1)
+        return st
+
+    def _ragged(self, st: RoundState) -> RoundState:
+        # one all-gather of the (R,) send counts buys the whole control
+        # plane: every rank derives every clamp and landing offset locally
+        me = jax.lax.axis_index(self.axis_name)
+        if st.flow == "credit":
+            # the granted counts, widened by this rank's own-entry advert
+            st.clamped = jnp.minimum(st.send_counts, st.credit_allow)
+            wide = jnp.concatenate(
+                [st.clamped, jnp.take(st.credits, me)[None].astype(st.clamped.dtype)]
+            )
+            gath = jax.lax.all_gather(wide, self.axis_name)  # (R, R+1)
+            st.cnt = gath[:, :self.num_ranks]
+            st.credits_out = gath[:, self.num_ranks].astype(jnp.int32)
+        else:
+            st.clamped = st.send_counts
+            st.cnt = jax.lax.all_gather(st.send_counts, self.axis_name)
+        st.send_sizes, st.out_offsets, st.recv_counts = ragged_control_plane(
+            st.cnt, me, self.capacity
+        )
         return st
 
     def _credit_recv(self, st: RoundState, counts: jax.Array) -> jax.Array:
@@ -793,6 +899,16 @@ class CountExchange:
         return recv[:, :-1]
 
     def shard(self, st: RoundState, k: int) -> RoundState:
+        if self.kind == "ragged":
+            # shard 0 rides the bulk control plane; every later shard runs
+            # its own count collective and replicated control plane
+            if k == 0:
+                st.shard_plane = (st.send_sizes, st.out_offsets, st.recv_counts)
+            else:
+                cnt_k = jax.lax.all_gather(st.clamped, self.axis_name)
+                me = jax.lax.axis_index(self.axis_name)
+                st.shard_plane = ragged_control_plane(cnt_k, me, self.capacity)
+            return st
         if self.kind != "tier":
             return self(st)
         # Ship each shard's OWN chunk counts; the receiver sums them back to
@@ -819,29 +935,58 @@ class CountExchange:
 class PayloadExchange:
     """The payload collective: ONE all_to_all of the (current shard's) send
     buffer.  With ``collect=True`` (sharded non-final tiers) the received
-    blocks are accumulated for :class:`Reassemble`."""
+    blocks are accumulated for :class:`Reassemble`.  ``kind="ragged"``: ONE
+    ``ragged_all_to_all`` of the contiguous segments straight into the
+    (capacity, W) receive queue; shard ``k`` ships rows ``[k·chunk,
+    (k+1)·chunk)`` of every segment at the bulk landing offsets."""
 
     axis_name: Any
     collect: bool = False
+    kind: str = "padded"
+    capacity: int = 0  # ragged: receive queue rows
+    shards: int = 1
 
     def __call__(self, st: RoundState) -> RoundState:
+        if self.kind == "ragged":
+            out = jnp.zeros((self.capacity, st.send_buf.shape[1]), st.send_buf.dtype)
+            st.out = jax.lax.ragged_all_to_all(
+                st.send_buf, out, input_offsets=st.base,
+                send_sizes=st.send_sizes, output_offsets=st.out_offsets,
+                recv_sizes=st.recv_counts, axis_name=self.axis_name,
+            )
+            return st
         st.recv_buf = a2a(st.send_buf, self.axis_name)
         if self.collect:
             st.recv_blocks.append(st.recv_buf)
         return st
 
     def shard(self, st: RoundState, k: int) -> RoundState:
-        return self(st)
+        if self.kind != "ragged":
+            return self(st)
+        if k == 0:
+            st.out = jnp.zeros((self.capacity, st.send_buf.shape[1]), st.send_buf.dtype)
+        chunk = self.capacity // self.shards
+        s_ss, s_oo, s_rs = st.shard_plane
+        st.out = jax.lax.ragged_all_to_all(
+            st.send_buf, st.out,
+            input_offsets=st.base + jnp.minimum(k * chunk, s_ss),
+            send_sizes=jnp.clip(s_ss - k * chunk, 0, chunk),
+            output_offsets=s_oo + jnp.minimum(k * chunk, s_ss),
+            recv_sizes=jnp.clip(s_rs - k * chunk, 0, chunk),
+            axis_name=self.axis_name,
+        )
+        return st
 
 
 @dataclasses.dataclass(frozen=True)
 class Unmarshal:
     """Receive-side compaction into the destination queue.  ``kind="flat"``
     reads the spill front SpillExtract reserved; ``kind="final"`` (the last
-    hierarchical tier) reserves the accumulated mid-route spill run.  Sharded
-    mode accumulates each shard's rows at their bulk positions
-    (:func:`compact_shard`) and closes the count/drop accounting on the last
-    shard."""
+    hierarchical tier) reserves the accumulated mid-route spill run;
+    ``kind="ragged"`` only opens the spill front behind arrivals the
+    collective already compacted.  Sharded mode accumulates each shard's
+    rows at their bulk positions (:func:`compact_shard`) and closes the
+    count/drop accounting on the last shard."""
 
     capacity: int
     shards: int = 1
@@ -854,10 +999,27 @@ class Unmarshal:
         return st.front
 
     def __call__(self, st: RoundState) -> RoundState:
+        if self.kind == "ragged":
+            return self._ragged(st)
         st.out, st.new_count, st.recv_drops = compact_blocks(
             st.recv_buf, st.recv_counts, self.capacity,
             use_pallas=st.use_pallas, front=self._front(st),
         )
+        return st
+
+    def _ragged(self, st: RoundState) -> RoundState:
+        # the collective already wrote the arrivals compacted; in retain
+        # mode open the spill front by one local gather (the landing
+        # offsets are fixed by the replicated control plane) — arrivals
+        # pushed past capacity are the receiver-admission loss
+        st.new_count = jnp.sum(st.recv_counts)
+        st.recv_drops = jnp.zeros((), st.send_counts.dtype)
+        if st.retain:
+            lane = jnp.arange(self.capacity, dtype=jnp.int32)
+            st.out = jnp.take(st.out, jnp.clip(lane - st.front, 0, self.capacity - 1), axis=0)
+            admitted = jnp.minimum(st.new_count, self.capacity - st.front)
+            st.recv_drops = st.new_count - admitted
+            st.new_count = admitted
         return st
 
     def shard(self, st: RoundState, k: int) -> RoundState:
@@ -949,16 +1111,31 @@ class Pipelined:
     def __call__(self, st: RoundState) -> RoundState:
         for k in range(self.shards):
             for stage in self.stages:
-                st = stage.shard(st, k)
+                with _scope(stage):
+                    st = stage.shard(st, k)
         return st
 
 
+def _scope(stage):
+    """The stage's device scope, ``rafi.<stage>`` from its class name
+    (``SpillExtract`` → ``rafi.spill_extract``): every op the stage emits
+    carries it in its ``op_name`` metadata."""
+    name = re.sub(r"(?<!^)(?=[A-Z])", "_", type(stage).__name__).lower()
+    return jax.named_scope(f"rafi.{name}")
+
+
 def compose(*stage_seq):
-    """Run stages in sequence over a :class:`RoundState` — the bulk graph."""
+    """Run stages in sequence over a :class:`RoundState` — the bulk graph —
+    each under its device scope (a :class:`Pipelined` group scopes each
+    shard's stages itself)."""
 
     def run(st: RoundState) -> RoundState:
         for stage in stage_seq:
-            st = stage(st)
+            if isinstance(stage, Pipelined):
+                st = stage(st)
+                continue
+            with _scope(stage):
+                st = stage(st)
         return st
 
     return run

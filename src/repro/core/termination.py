@@ -27,6 +27,11 @@ exactly start + one full-length segment + finalize; the checkpoint/resume
 host drive (``repro.core.recovery``) runs W-round segments instead,
 snapshotting the carry between them — same traced body, so an uninterrupted
 run and a segmented run execute bit-identical programs round for round.
+
+Device scopes: start, segment and finalize run under ``rafi.drive`` and the
+app's ``round_fn`` under ``rafi.app``, so every op of a burst's program names
+its layer in the ``op_name`` a profiler trace shows (``forward_work`` and the
+queue operations add ``rafi.forward``, ``rafi.enqueue`` and finer scopes).
 """
 from __future__ import annotations
 
@@ -86,6 +91,7 @@ def _split_retained(q: WorkQueue) -> Tuple[jax.Array, WorkQueue]:
     return n_ret, view
 
 
+@jax.named_scope("rafi.merge")
 def _merge_retained(
     q: WorkQueue, n_ret: jax.Array, out_q: WorkQueue, age: jax.Array,
     axis_name, limit=None,
@@ -175,6 +181,7 @@ def _fwd(q, age, cfg, health, credits=None):
     return new_q, total, age_out, credits_out, stats
 
 
+@jax.named_scope("rafi.drive")
 def drive_start(
     q0: WorkQueue,
     aux0: Any,
@@ -236,6 +243,7 @@ def drive_start(
     return carry
 
 
+@jax.named_scope("rafi.drive")
 def drive_segment(
     round_fn: Callable[[WorkQueue, Any, jax.Array], Tuple[WorkQueue, Any]],
     carry: Dict[str, Any],
@@ -293,7 +301,8 @@ def drive_segment(
                     kw["headroom"] = jnp.maximum(limit - n_ret, 0)
             elif wants_headroom:
                 kw["headroom"] = jnp.maximum(cfg.capacity - n_ret, 0)
-            out_q, aux = round_fn(view, aux, rnd, **kw)
+            with jax.named_scope("rafi.app"):
+                out_q, aux = round_fn(view, aux, rnd, **kw)
             fwd_q, age_in = _merge_retained(
                 q, n_ret, out_q, c["age"], cfg.axis_name, limit
             )
@@ -301,7 +310,8 @@ def drive_segment(
         else:
             consumed = q.count
             kw = {"headroom": jnp.int32(cfg.capacity)} if wants_headroom else {}
-            fwd_q, aux = round_fn(q, aux, rnd, **kw)
+            with jax.named_scope("rafi.app"):
+                fwd_q, aux = round_fn(q, aux, rnd, **kw)
             age_in = None
             attempted = fwd_q.count + fwd_q.drops
         new_q, total, age_out, credits_out, stats = _fwd(
@@ -340,6 +350,7 @@ def drive_segment(
     return jax.lax.while_loop(cond, body, carry)
 
 
+@jax.named_scope("rafi.drive")
 def drive_finalize(carry: Dict[str, Any], cfg: ForwardConfig):
     """Carry → results: fold the cumulative drops into the final queue and
     emit the ``run_until_done`` return tuple (see its docstring)."""

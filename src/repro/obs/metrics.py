@@ -23,6 +23,12 @@ emission overflow (ISSUE 9), receive totals and goodput.
 :func:`accounting_metrics` adds the conservation-watchdog terms of a
 segmented drive, :func:`checkpoint_metrics` the bytes/leaves of a published
 checkpoint manifest.
+
+:data:`REGISTRY` holds what the program itself declares while it is traced:
+``rafi_payload_rows_per_forward``, the rows one rank's payload pass moves
+per forward, summed over the exchange's stages (``core.stages.Marshal``
+sets it as it builds each send buffer; the last program traced wins).  This
+module imports nothing from ``repro.core``, so the core can record here.
 """
 from __future__ import annotations
 
@@ -35,7 +41,10 @@ import numpy as np
 from repro.telemetry import stats as TS
 
 __all__ = [
+    "PAYLOAD_ROWS",
+    "REGISTRY",
     "Metric",
+    "Registry",
     "accounting_metrics",
     "burst_metrics",
     "checkpoint_metrics",
@@ -69,6 +78,32 @@ def _m(name: str, kind: str, value, help: str = "", **labels) -> Metric:
         labels=tuple(sorted((k, str(v)) for k, v in labels.items())),
         help=help,
     )
+
+
+class Registry:
+    """Gauges a program declares at trace time, by name: the last value set
+    wins.  :data:`REGISTRY` is the process-wide one the library writes."""
+
+    def __init__(self):
+        self._gauges: Dict[str, Metric] = {}
+
+    def set_gauge(self, name: str, value, help: str = "") -> None:
+        self._gauges[name] = _m(name, "gauge", value, help)
+
+    def get(self, name: str) -> Optional[float]:
+        """The gauge's value, or ``None`` if nothing declared it."""
+        m = self._gauges.get(name)
+        return None if m is None else m.value
+
+    def collect(self) -> List[Metric]:
+        return [self._gauges[k] for k in sorted(self._gauges)]
+
+    def clear(self) -> None:
+        self._gauges.clear()
+
+
+REGISTRY = Registry()
+PAYLOAD_ROWS = "rafi_payload_rows_per_forward"
 
 
 def from_summary(summary: Dict[str, Any], *, prefix: str = "rafi") -> List[Metric]:

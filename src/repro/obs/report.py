@@ -19,8 +19,6 @@ measured number against the law that governs it:
   (``1 - Σ wasted / Σ wire``) and checked against both the run's own
   recorded number and, when the capture carries the scenario's
   offered/drain rates, the ``goodput_model`` prediction;
-* **overlap** (the overlap law, PR 8): a measured ``phase_us`` split is
-  bracketed by ``overlap_efficiency_model`` at ``async_fraction`` 0 and 1;
 * **liveness**: livelock (rounds exhausted, backlog resident, nothing
   moving over the tail of the ring window), starvation (a rank's delivered
   share collapsed vs the per-rank median — only flagged when a healthy majority
@@ -38,8 +36,7 @@ Capture format — one JSON object::
      "runs": [{"name", "flow", "ledger": {...}, "trace": {...},
                "tier_capacities", "capacity", "metrics": [...],
                "delivered_by_rank": [...], "model": {...}}, ...],
-     "events": [...],            # optional obs.trace event list
-     "phase_us": {...}, "phase_meta": {...}}   # optional obs.phases split
+     "events": [...]}            # optional obs.trace event list
 
 :func:`chaos_capture` builds a run entry from a ``repro.chaos.run_scenario``
 result dict; :func:`save_capture` / :func:`load_capture` round-trip the file.
@@ -130,16 +127,13 @@ def chaos_capture(
 
 
 def save_capture(path, runs: List[Dict[str, Any]], *, events=None,
-                 phase_us=None, phase_meta=None, meta=None) -> str:
+                 meta=None) -> str:
     cap: Dict[str, Any] = {"meta": dict(meta or {}), "runs": list(runs)}
     if events is not None:
         cap["events"] = [
             {**e, "args": {k: _plain(v) for k, v in (e.get("args") or {}).items()}}
             for e in events
         ]
-    if phase_us is not None:
-        cap["phase_us"] = {k: float(v) for k, v in phase_us.items()}
-        cap["phase_meta"] = dict(phase_meta or {})
     with open(path, "w") as f:
         json.dump(cap, f)
     return str(path)
@@ -339,34 +333,6 @@ def _analyze_run(run: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
-def _analyze_phases(capture: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """Bracket a measured phase split with the overlap law's model at
-    async_fraction 0 (synchronous fabric) and 1 (DMA fabric)."""
-    phase_us = capture.get("phase_us")
-    if not phase_us:
-        return None
-    from repro.roofline.analysis import overlap_efficiency_model
-
-    meta = capture.get("phase_meta", {})
-    shards = int(meta.get("shards", 1))
-    bulk_keys = {k: v for k, v in phase_us.items()
-                 if "_" not in k or not k.split("_")[0].startswith("shard")}
-    sync = overlap_efficiency_model(bulk_keys, shards, async_fraction=0.0)
-    ici = overlap_efficiency_model(bulk_keys, shards, async_fraction=1.0)
-    wire = sync["wire_us"]
-    comp = sync["compute_us"]
-    total = wire + comp
-    return {
-        "phase_us": {k: float(v) for k, v in phase_us.items()},
-        "shards": shards,
-        "compute_us": comp,
-        "wire_us": wire,
-        "wire_fraction": wire / total if total else 0.0,
-        "pipelined_bracket_us": [ici["pipelined_us"], sync["pipelined_us"]],
-        "speedup_bracket": [sync["speedup"], ici["speedup"]],
-    }
-
-
 def _analyze_events(capture: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     """Host-trace digest: per-category counts, slowest spans, chaos faults,
     autotune re-plans, checkpoint cadence."""
@@ -408,9 +374,6 @@ def analyze(capture: Dict[str, Any]) -> Dict[str, Any]:
         "runs": runs,
         "degraded_runs": [r["name"] for r in runs if r["degraded"]],
     }
-    phases = _analyze_phases(capture)
-    if phases:
-        report["phases"] = phases
     events = _analyze_events(capture)
     if events:
         report["trace_digest"] = events
@@ -435,17 +398,6 @@ def render(report: Dict[str, Any]) -> str:
         for c in r["checks"]:
             mark = "ok " if c["ok"] else "FAIL"
             lines.append(f"  [{mark}] {c['check']}: {c['detail']}")
-        lines.append("")
-    if "phases" in report:
-        p = report["phases"]
-        lines.append("## phase split (one round)")
-        for k, v in p["phase_us"].items():
-            lines.append(f"  {k}: {v:.1f} us")
-        lines.append(
-            f"  wire fraction {p['wire_fraction']:.2f}; pipelined x{p['shards']} "
-            f"bracket [{p['pipelined_bracket_us'][0]:.1f}, "
-            f"{p['pipelined_bracket_us'][1]:.1f}] us (ici..sync)"
-        )
         lines.append("")
     if "trace_digest" in report:
         d = report["trace_digest"]

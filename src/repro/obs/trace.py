@@ -13,6 +13,12 @@ program lowers BIT-identically to the untraced one (guarded in
 ``tests/test_collective_budget.py``) — observation adds zero collectives by
 construction, not by audit.
 
+Every :func:`span` also opens ``jax.profiler.TraceAnnotation("rafi.<name>")``,
+tracer or not, so the drive's host spans land on the host plane of any
+profiler trace, on the device's clock, beside the ``rafi.*`` device scopes
+the traced program carries (``jax.named_scope`` in ``repro.core``).  With no
+profiler running an annotation is a no-op.
+
 Two ways to turn it on:
 
 * explicitly — ``with trace.capture() as tr: ...; tr.save(path)``;
@@ -25,8 +31,7 @@ Export is Chrome/Perfetto ``trace_event`` JSON (``chrome://tracing``,
 https://ui.perfetto.dev): spans are complete ``"X"`` events, instants are
 ``"i"``; the track layout (``pid``/``tid``) is one process track per rank
 and one thread track per tier — host-only spans live on rank track 0,
-tier track 0.  ``obs.phases`` produces per-rank / per-tier device phase
-timings in the same layout so both merge into one timeline.
+tier track 0.
 
 This module imports nothing from the rest of ``repro`` — core modules hook
 it at import time without cycles.
@@ -40,6 +45,8 @@ import json
 import os
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -64,7 +71,6 @@ CAT_TUNE = "tune"            # autotune re-plans
 CAT_HEALTH = "health"        # health-mask transitions
 CAT_CHAOS = "chaos"          # scenario runs, fault injections
 CAT_ROUTE = "route"          # rebalance / cycling trace-time records
-CAT_PHASE = "phase"          # device per-phase timings (obs.phases)
 
 
 def _now_us() -> float:
@@ -123,13 +129,6 @@ class Tracer:
             yield sp
         finally:
             sp.close()
-
-    def phase_event(self, name: str, *, ts_us: float, dur_us: float,
-                    rank: int = 0, tier: int = 0, **args: Any) -> None:
-        """A device phase timing placed explicitly on the (rank, tier)
-        track — how ``obs.phases`` merges its measured timeline in."""
-        self._record(name=name, cat=CAT_PHASE, ph="X", ts=ts_us, dur=dur_us,
-                     rank=rank, tier=tier, args=args)
 
     # -- views -----------------------------------------------------------
     def select(self, cat: Optional[str] = None,
@@ -262,14 +261,16 @@ def event(name: str, cat: str = CAT_DRIVE, **kw: Any) -> None:
 
 @contextlib.contextmanager
 def span(name: str, cat: str = CAT_DRIVE, **kw: Any):
-    """Span on the ambient tracer; yields the :class:`Span` or a no-op
-    stand-in when tracing is off (callers ``sp.set(...)`` unconditionally)."""
-    tr = current()
-    if tr is None:
-        yield _NOOP_SPAN
-        return
-    with tr.span(name, cat, **kw) as sp:
-        yield sp
+    """Span on the ambient tracer and, always, a profiler annotation
+    ``rafi.<name>``; yields the :class:`Span` or a no-op stand-in when
+    tracing is off (callers ``sp.set(...)`` unconditionally)."""
+    with TraceAnnotation(f"rafi.{name}"):
+        tr = current()
+        if tr is None:
+            yield _NOOP_SPAN
+            return
+        with tr.span(name, cat, **kw) as sp:
+            yield sp
 
 
 class _NoopSpan:

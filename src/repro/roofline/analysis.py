@@ -471,63 +471,6 @@ def marshal_cost_model(
     }
 
 
-def overlap_efficiency_model(
-    phase_us: Dict[str, float],
-    shards: int,
-    *,
-    wire_phases=("count_collective", "payload_collective"),
-    async_fraction: float = 1.0,
-) -> Dict[str, float]:
-    """Model: the overlap law's walltime — software-pipelining one forwarding
-    round into ``shards`` micro-shards (``ForwardConfig.pipeline_shards``).
-
-    Input is the measured per-phase breakdown of ONE bulk round (the
-    ``fwd_profile_*`` rows: marshal, count_collective, payload_collective,
-    unmarshal).  Phases in ``wire_phases`` are collective time ``w``; the
-    rest is send/receive compute ``c``.  With S shards each phase splits into
-    S chunks of 1/S the work, and a fabric that can ship one chunk while the
-    VPU marshals the next hides ``async_fraction`` of the wire time behind
-    compute.  The classic fill/drain pipeline bound:
-
-        T(S, a) = (1 - a)·w  +  (c + a·w)/S  +  (S - 1)/S · max(c, a·w)
-
-    * ``a = 1`` (DMA/NIC fabric — TPU ICI, the paper's target): steady state
-      overlaps perfectly, T → max(c, w) as S grows; speedup caps at
-      ``(c + w)/max(c, w)``.
-    * ``a = 0`` (synchronous fabric — XLA:CPU's memcpy collectives): T equals
-      the bulk round — the model predicts NO overlap win, so any measured
-      gain there is the locality corollary (each 1/S chunk is marshalled,
-      shipped and compacted while still cache-resident) and any loss is the
-      S× launch overhead.  The gate brackets measurements with both bounds.
-
-    Returns ``{"bulk_us", "pipelined_us", "speedup", "efficiency",
-    "compute_us", "wire_us"}`` — ``efficiency`` is the achieved fraction of
-    the perfect-overlap bound ``max(c, w)``.
-    """
-    if shards < 1:
-        raise ValueError(f"shards must be >= 1, got {shards}")
-    if not 0.0 <= async_fraction <= 1.0:
-        raise ValueError(f"async_fraction must be in [0, 1], got {async_fraction}")
-    w = float(sum(us for ph, us in phase_us.items() if ph in wire_phases))
-    c = float(sum(us for ph, us in phase_us.items() if ph not in wire_phases))
-    bulk = c + w
-    a = float(async_fraction)
-    hidden = a * w
-    pipelined = (
-        (1.0 - a) * w
-        + (c + hidden) / shards
-        + (shards - 1) / shards * max(c, hidden)
-    )
-    return {
-        "bulk_us": bulk,
-        "pipelined_us": pipelined,
-        "speedup": bulk / pipelined if pipelined > 0 else float("inf"),
-        "efficiency": max(c, w) / pipelined if pipelined > 0 else 1.0,
-        "compute_us": c,
-        "wire_us": w,
-    }
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Sum of result-shape bytes per collective kind; handles both post-SPMD
     HLO (``all-gather(...)``) and StableHLO (``"stablehlo.all_gather"``)."""

@@ -99,7 +99,8 @@ def test_perfetto_export_structure(tmp_path):
     with OT.capture() as tr:
         with OT.span("burst", OT.CAT_DRIVE, rounds=3):
             OT.event("fault", OT.CAT_CHAOS, mask=[0, 1])
-        tr.phase_event("marshal", ts_us=1.0, dur_us=5.0, rank=2, tier=1)
+        with OT.span("marshal", OT.CAT_DRIVE, rank=2, tier=1):
+            pass
     doc = tr.to_perfetto()
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     rows = doc["traceEvents"]
@@ -203,7 +204,7 @@ def test_checkpointed_drive_records_recovery_events(mesh8, tmp_path):
         "chaos.run_scenario_checkpointed", "chaos.preempt_scheduled",
         "chaos.elastic_resume", "recovery.run_checkpointed",
         "recovery.boundary", "recovery.save", "recovery.preempt",
-        "recovery.resume_run",
+        "recovery.resume_run", "recovery.segment",
     } <= names
     saves = tr.select(name="recovery.save")
     assert all(s["args"]["bytes"] > 0 for s in saves)
@@ -414,61 +415,184 @@ def test_analyzer_flags_ledger_violation(mesh8, tmp_path):
     assert "tampered" in report["degraded_runs"]
 
 
-# ----------------------------------------------------------- obs.phases
-@pytest.mark.obs
-@pytest.mark.parametrize(
-    "kw,want",
-    [
-        (
-            dict(exchange="padded", peer_capacity=8),
-            {"marshal", "count_collective", "payload_collective",
-             "unmarshal"},
-        ),
-        (
-            dict(exchange="padded", peer_capacity=8, pipeline_shards=2),
-            {"marshal", "count_collective", "payload_collective",
-             "unmarshal"}
-            | {f"shard{k}_{p}" for k in range(2)
-               for p in ("marshal", "payload_collective", "unmarshal")},
-        ),
-    ],
-    ids=["padded", "pipelined"],
-)
-def test_profile_phases_key_vocabulary(mesh8, kw, want):
+# ------------------------------------------------- device scopes, counter
+_STAGES = {"spill_extract", "marshal", "count_exchange", "payload_exchange", "unmarshal"}
+_ROUND = {"drive", "app", "enqueue", "forward", "plan", "termination"}
+# backend and mode -> (ForwardConfig keywords, the stages it runs)
+_BACKENDS = {
+    "padded_sort": (dict(exchange="padded", peer_capacity=16), _STAGES),
+    "padded_scatter": (dict(exchange="padded", peer_capacity=16, marshal="scatter"), _STAGES),
+    "pipelined": (dict(exchange="padded", peer_capacity=16, pipeline_shards=2), _STAGES),
+    "hierarchical": (
+        dict(exchange="hierarchical", level_sizes=(2, 2, 2)), _STAGES | {"advance_tier"},
+    ),
+    "retain_credit": (
+        dict(exchange="padded", peer_capacity=16, overflow="retain", flow="credit"),
+        _STAGES | {"credit_gate", "merge"},
+    ),
+    "ragged": (dict(exchange="ragged"), _STAGES),
+    "onehot": (dict(exchange="onehot"), set()),  # the oracle runs no stage objects
+}
+# instructions a module carries without computing (or XLA inserts as such)
+_PLUMBING = {"parameter", "constant", "tuple", "get-tuple-element", "copy", "bitcast"}
+
+
+def _burst(mesh, cfg, capacity=64):
+    """A 20-row burst per rank that bounces for three rounds, compiled where
+    the backend runs (ragged only lowers on XLA:CPU); returns its HLO text."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import compat
+    from repro.core import enqueue, make_queue, run_until_done
+
+    from helpers import Particle, particle_proto
+
+    axes = tuple(mesh.axis_names)
+    R, C = mesh.size, capacity
+
+    def burst(x):
+        lane = jnp.arange(C, dtype=jnp.int32)
+        me = jax.lax.axis_index(axes)
+        items = Particle(uid=lane + x[0], pos=jnp.zeros((C, 3)))
+        q0 = enqueue(make_queue(particle_proto(), C), items, (lane + me) % R, lane < 20)
+
+        def process(q, aux, rnd):
+            valid = jnp.arange(C) < q.count
+            dst = (q.items.uid * 7 + rnd) % R
+            out = enqueue(make_queue(particle_proto(), C), q.items, dst, valid & (rnd < 3))
+            return out, aux + jnp.sum(jnp.where(valid, q.items.uid, 0))
+
+        _q, aux, rounds, _done, *_ = run_until_done(
+            process, q0, jnp.zeros((), jnp.int32), cfg, max_rounds=8
+        )
+        return aux[None] + rounds
+
+    low = jax.jit(jax.shard_map(burst, mesh=mesh, in_specs=P(axes), out_specs=P(axes))).lower(
+        jnp.zeros((R,), jnp.int32)
+    )
+    if cfg.exchange == "ragged" and not compat.ragged_executes():
+        return low.as_text(dialect="hlo", debug_info=True)
+    return low.compile().as_text()
+
+
+def _computations(hlo):
+    """``{name: [instruction lines]}`` of an HLO module's text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            head = line.split()
+            cur = (head[1] if head[0] == "ENTRY" else head[0]).lstrip("%")
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            comps[cur].append(line.strip())
+    return comps
+
+
+def _opcode(line):
+    rhs = line.split(" = ", 1)[1]
+    if rhs.startswith("("):  # tuple-shaped result: skip to its closing paren
+        depth = 0
+        for i, ch in enumerate(rhs):
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                rhs = rhs[i + 1:]
+                break
+    else:
+        rhs = rhs.split(" ", 1)[1]
+    return rhs.strip().split("(", 1)[0]
+
+
+@pytest.mark.parametrize("backend", list(_BACKENDS))
+def test_round_ops_carry_rafi_scopes(backend, mesh8, mesh_pods222):
+    """Every computing instruction of the drive's while body carries
+    ``rafi.drive`` (XLA's plumbing, ops it builds from constants alone and
+    ops its rewrites leave without any ``op_name`` are exempt), and every
+    stage the backend runs shows up under its ``rafi.*`` scope."""
+    import re
+
     from repro.core import ForwardConfig
-    from repro.obs.phases import profile_phases, tier_of_phase
 
-    from helpers import ray_proto
+    kw, stages = _BACKENDS[backend]
+    hier = kw["exchange"] == "hierarchical"
+    mesh = mesh_pods222 if hier else mesh8
+    axes = tuple(mesh.axis_names) if hier else "data"
+    hlo = _burst(mesh, ForwardConfig(axes, R, 64, **kw))
 
-    cfg = ForwardConfig("data", R, 64, **kw)
-    calls = []
+    comps = _computations(hlo)
+    consts = {
+        line.split(" = ", 1)[0].lstrip("%") for lines in comps.values() for line in lines
+        if _opcode(line) == "constant"
+    }
+    bodies = set(re.findall(r"while\(.*?body=%?([\w.\-]+)", hlo))
+    assert bodies
+    unscoped = []
+    for body in bodies:
+        for line in comps[body]:
+            operands = re.findall(r"%?([\w.\-]+)", line.split("(", 1)[1].split(")", 1)[0])
+            if (
+                _opcode(line) in _PLUMBING
+                or "op_name=" not in line
+                or (operands and set(operands) <= consts)
+            ):
+                continue
+            if "rafi.drive" not in line:
+                unscoped.append(line)
+    assert not unscoped, unscoped[:5]
+    found = set(re.findall(r"rafi\.([a-z_]+)", hlo))
+    assert stages | _ROUND <= found, sorted(stages | _ROUND - found)
 
-    def timeit(f, x):
-        calls.append(f)
-        return 1.0, f(x)
 
-    phase_us = profile_phases(
-        cfg, mesh8, n_emit=8, cap=64, proto=ray_proto(), timeit=timeit
-    )
-    assert set(phase_us) == want
-    assert len(calls) == len(want)  # one timed program per phase
-    assert all(tier_of_phase(k) == 0 for k in phase_us)
+def test_span_lands_on_profiler_host_plane(tmp_path, monkeypatch):
+    """With no in-memory tracer installed, ``obs.trace.span`` still writes
+    ``rafi.<name>`` onto the host plane of a profiler trace."""
+    import glob
+
+    import jax
+
+    monkeypatch.delenv(OT.ENV_VAR, raising=False)
+    monkeypatch.setattr(OT, "_ENV_CHECKED", True)
+    OT.uninstall()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with OT.span("probe.span", OT.CAT_DRIVE) as sp:
+            assert sp.set(x=1) is sp  # the no-op stand-in
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        e.name
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert "rafi.probe.span" in names
 
 
-@pytest.mark.obs
-def test_phases_to_perfetto_tracks():
-    from repro.obs import phases as OP
+@pytest.mark.parametrize(
+    "kw,rows",
+    [
+        (dict(exchange="padded", peer_capacity=16), R * 16),
+        (dict(exchange="padded", peer_capacity=16, pipeline_shards=4), R * 16),
+        (dict(exchange="hierarchical", level_sizes=(2, 2, 2),
+              level_capacities=(24, 16, 8)), 2 * 24 + 2 * 16 + 2 * 8),
+    ],
+    ids=["padded", "pipelined", "hierarchical"],
+)
+def test_payload_rows_gauge(kw, rows, mesh8, mesh_pods222):
+    """Tracing a burst declares ``rafi_payload_rows_per_forward``: the rows
+    one rank's send buffers hold per forward, summed over its stages
+    (``R · peer_capacity`` for the flat padded exchange)."""
+    from repro.core import ForwardConfig
 
-    doc = OP.to_perfetto(
-        {"marshal": 10.0, "tier1_payload_collective": 20.0},
-        num_ranks=2, tag="t", t0_us=0.0,
-    )
-    rows = [r for r in doc["traceEvents"] if r["ph"] == "X"]
-    # every rank gets its own copy of the measured phase timeline
-    assert {r["pid"] for r in rows} == {0, 1}
-    # span names carry the tag prefix; tid is the phase's tier
-    tiers = {r["name"]: r["tid"] for r in rows if r["pid"] == 0}
-    assert tiers["t:marshal"] == 0 and tiers["t:tier1_payload_collective"] == 1
-    # phases are laid end to end per rank
-    starts = sorted(r["ts"] for r in rows if r["pid"] == 0)
-    assert starts == [0.0, 10.0]
+    hier = kw["exchange"] == "hierarchical"
+    mesh = mesh_pods222 if hier else mesh8
+    OM.REGISTRY.clear()
+    assert OM.REGISTRY.get(OM.PAYLOAD_ROWS) is None
+    _burst(mesh, ForwardConfig(tuple(mesh.axis_names) if hier else "data", R, 64, **kw))
+    assert OM.REGISTRY.get(OM.PAYLOAD_ROWS) == rows
+    (m,) = [m for m in OM.REGISTRY.collect() if m.name == OM.PAYLOAD_ROWS]
+    assert m.kind == "gauge"
