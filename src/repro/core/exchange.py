@@ -129,7 +129,7 @@ clamp's cut rows are exactly the per-segment TAILS of the marshalled order,
 so each block is extracted with the same composed positional arithmetic the
 send gather uses (one extra gather per clamp site — no conditional, no
 per-lane masks, no scatter), and the receive-side compaction lands arrivals
-BEHIND a reserved queue front (a shifted offset in the scatter it already
+BEHIND a reserved queue front (a shifted offset in the gather it already
 runs).  ``forward_work`` then just selects the blocks into that front
 (stable block-then-row order = FIFO oldest-first) and retries them next
 round: the lossless law.  Retention is pure local compaction: what ships is
@@ -218,7 +218,10 @@ def exchange_padded(
     n_spill)`` inserted before the stats — extracted as the marshalled
     order's segment tails in the same pass style as the send gather — and
     the receive compaction lands arrivals BEHIND the reserved spill front,
-    so ``drops`` reduces to the receiver-side admission count.
+    so ``drops`` reduces to the receiver-side admission count.  The
+    receive compaction is the inverse gather of the padded blocks: each
+    queue row reads its own source row (``stages.compact_blocks``), so the
+    receive side, like the send side, moves the payload in ONE pass.
 
     With ``pipeline_shards=S > 1`` the Marshal→…→Unmarshal chain runs S
     times over slot-row micro-shards, interleaved (``stages.Pipelined``):

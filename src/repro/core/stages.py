@@ -13,8 +13,10 @@ stages in a row, whatever the fabric layout:
   CountExchange  the control plane: the tiny per-peer count collective.
   PayloadExchange the payload collective: ONE all_to_all of the send buffer.
   Unmarshal      receive-side compaction into the destination queue
-                 (``out[roff[g] + s] = recv[g, s]``), rows past capacity
-                 dropped; retain mode lands arrivals behind the spill front.
+                 (``out[roff[g] + s] = recv[g, s]``, computed as the inverse
+                 gather: each queue row reads its source row), rows past
+                 capacity dropped; retain mode lands arrivals behind the
+                 spill front.
 
 Pre-refactor each backend inlined all five; here they are small stage
 objects over an explicit :class:`RoundState`, and the backends are thin
@@ -255,11 +257,24 @@ def compact_blocks(
     front=None,  # retain mode: rows [0, front) are reserved for the spill
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Receive-side compaction shared by the padded-slot exchanges:
-    ``out[roff[g] + s] = recv_buf[g, s]`` for ``s < recv_counts[g]``, rows
-    past ``capacity`` dropped (§3.3).  Returns ``(out, new_count, drops)``.
+    ``out[roff[g] + s] = recv_buf[g, s]`` for ``s < recv_counts[g]``
+    (``recv_counts[g] <= S``: the exchanged counts are clamped to the slot),
+    rows past ``capacity`` dropped (§3.3), every other row zero.  Returns
+    ``(out, new_count, drops)``.
 
-    With ``front`` the arrivals land shifted by that many rows — the same
-    scatter places them BEHIND the retained spill at zero extra cost, and
+    The XLA path computes it as the INVERSE gather, not as a row scatter
+    (XLA lowers a W-word row scatter far worse than the equivalent gather,
+    as on the send side — :func:`scatter_rows`): queue row ``j`` with
+    ``j' = j − front`` reads flat receive row
+    ``j' + Σ_{g<G−1} [j' ≥ incl[g]]·(S − recv_counts[g])`` (``incl`` the
+    inclusive count prefix) while ``0 ≤ j' < incl[G−1]``.  G is static, so
+    the sum is G − 1 elementwise compare-and-adds that fuse into the
+    gather's index; each queue row is read once and written once, with no
+    scatter and no index sort.  For ``G = 1`` the index is ``j'`` itself: a
+    masked copy.
+
+    With ``front`` the arrivals land shifted by that many rows — BEHIND the
+    retained spill at zero extra cost (rows ``[0, front)`` stay zero), and
     ``new_count``/``drops`` account against the reduced room.
     """
     G, S, W = recv_buf.shape
@@ -271,13 +286,17 @@ def compact_blocks(
 
         out = marshal_ops.fused_unmarshal(recv_buf, roff, recv_counts, capacity=capacity)
     else:
-        g_idx = jnp.repeat(jnp.arange(G, dtype=jnp.int32), S)
-        s_idx = jnp.tile(jnp.arange(S, dtype=jnp.int32), G)
-        dstpos = roff[g_idx] + s_idx
-        ok = s_idx < recv_counts[g_idx]
-        slot = jnp.where(ok & (dstpos < capacity), dstpos, capacity)
-        out = jnp.zeros((capacity, W), recv_buf.dtype)
-        out = out.at[slot].set(recv_buf.reshape(G * S, W), mode="drop")
+        incl = jnp.cumsum(recv_counts)
+        j = jnp.arange(capacity, dtype=incl.dtype)
+        if front is not None:
+            j = j - front
+        src = j
+        for g in range(G - 1):  # rows past the last block are masked below
+            src = src + jnp.where(j >= incl[g], S - recv_counts[g], 0)
+        # masked rows may point out of range: the take clips them
+        rows = jnp.take(recv_buf.reshape(G * S, W), src, axis=0, mode="clip")
+        ok = (j >= 0) & (j < incl[G - 1])
+        out = jnp.where(ok[:, None], rows, 0)
     total_recv = jnp.sum(recv_counts)
     room = capacity if front is None else jnp.clip(capacity - front, 0)
     new_count = jnp.minimum(total_recv, room)
@@ -297,8 +316,11 @@ def compact_shard(
     the SAME final positions the bulk compaction gives them
     (``roff[g] + row_offset + s``, valid while ``row_offset + s <
     recv_counts[g]``), so the union over shards is bit-exact with
-    :func:`compact_blocks`.  Always the XLA scatter path — per-shard
-    accumulation into a shared queue has no fused-unmarshal kernel.
+    :func:`compact_blocks`.  Always an XLA row scatter, unlike the bulk
+    path's inverse gather: each shard writes a disjoint subset of the shared
+    queue, which a gather would have to merge into the accumulator by a
+    ``where`` over every row per shard, and per-shard accumulation has no
+    fused-unmarshal kernel.
     """
     G, chunk, W = recv_buf.shape
     roff = jnp.cumsum(recv_counts) - recv_counts
@@ -980,7 +1002,9 @@ class PayloadExchange:
 
 @dataclasses.dataclass(frozen=True)
 class Unmarshal:
-    """Receive-side compaction into the destination queue.  ``kind="flat"``
+    """Receive-side compaction into the destination queue: each queue row
+    gathers its source row of the padded receive blocks
+    (:func:`compact_blocks`).  ``kind="flat"``
     reads the spill front SpillExtract reserved; ``kind="final"`` (the last
     hierarchical tier) reserves the accumulated mid-route spill run;
     ``kind="ragged"`` only opens the spill front behind arrivals the

@@ -740,15 +740,17 @@ def test_traced_metered_drive_leaves_lowering_bit_identical(mesh8):
 # installed JAX, so any change to what one round lowers to — a refactor that
 # was meant to be free, or a new JAX — shows up here first.  The telemetry
 # round lowers to the SAME program as the plain one: its stats are unused.
+# Re-pinned when the padded receive compaction (``stages.compact_blocks``)
+# became an inverse gather; ``onehot`` runs no compaction and kept its digest.
 _PRE_REFACTOR_SHA256 = {
-    "padded_sort": "b9ff2802e1d523eea6749d19aeb694aff1fd0f45cd626bebbc7981bd9fbb0615",
-    "padded_scatter": "4731458f9e7d41a38d68875443b71440739138a3bd1691a361212b5adf3b91f2",
-    "padded_retain": "93fd34828d386c3b067ad6d667e11deb525107f20dde135123dfba2d54dde12a",
-    "padded_telemetry": "b9ff2802e1d523eea6749d19aeb694aff1fd0f45cd626bebbc7981bd9fbb0615",
+    "padded_sort": "9dcdd9362b1dd24ada07d81c513644702130ae75bea5639362d1737e6d91ffd6",
+    "padded_scatter": "c3d80335b5e15ad83fd219d24ac7e8a91c19ade298a1e6619509e42cfa8416c1",
+    "padded_retain": "746f1e02d19e980946b0ad8e01c7d52110356a2cd9ed23e94697117b9ef884f2",
+    "padded_telemetry": "9dcdd9362b1dd24ada07d81c513644702130ae75bea5639362d1737e6d91ffd6",
     "onehot": "d6e4464d803b47854a1df6ec5ee838cfcf91403ec1fddd7a9f7b36ea65f39b9d",
-    "hier3_sort": "c7c035c54146f4f760500ec9d473d58c88925652c919d8710711e0b0063f7afd",
-    "hier3_scatter": "26417c13fb7f24d9e795753c6d3222bd3358c01be99373944e764b3c1266da94",
-    "hier3_retain": "1d40f34ee5b0e819bca8e1db6765b2f48990cb95903b98d1ae6167289a7dfe8c",
+    "hier3_sort": "6f734832be38bb2870de646fcb5a631fc4d2c8b8c02a1c57399dafde268eb14a",
+    "hier3_scatter": "74e9e409fdd13c49c6ece65e7e7b77e6ce803a578656f383717248b8b78e5d4b",
+    "hier3_retain": "40e828f8294b6ccec5dbc5271f3f8d27fb81bb908ac03b078299af235478da41",
 }
 
 _GOLDEN_CASES = {
