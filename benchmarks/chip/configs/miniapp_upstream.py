@@ -8,7 +8,8 @@ device in set-up, in one table; each burst reads its row by an index the
 previous burst returned, so no transfer to the device sits between two
 bursts (as upstream passes a burst's size to its seeding kernel).  The queue holds ``1000·128·R`` rows per rank, the exchange is
 padded with one queue of slots per peer, and the library's defaults apply
-otherwise.
+otherwise.  Each burst's per-rank sizes come from ``--seed``
+(:func:`jobs`, the traffic kind ``bursts``).
 
 The process step also folds a hash of every delivery (source rank and id,
 round, rank, position) into a per-rank uint32 sum, so a burst's answer,
@@ -19,7 +20,9 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import itertools
 from pathlib import Path
+from typing import Any, Dict, Iterator, List
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +36,9 @@ REFERENCE = Path(__file__).with_name("miniapp_upstream_reference.py")
 AXIS = "data"
 _MIX = (0x9E3779B1, 0x85EBCA77, 0xC2B2AE3D, 0x27D4EB2F, 0x165667B1)
 
+# the sizes a CPU test replaces in configs/miniapp_upstream.json
+SMALL = {"queue_rows_per_mesh_rank": 1024}
+
 # name -> limit; every comparison is exact (PERF.md has the readings)
 LIMITS = {
     "digest_off": 0,      # bursts whose delivery digest differs
@@ -41,6 +47,47 @@ LIMITS = {
     "drops": 0,           # rays the queues dropped
     "not_done": 0,        # bursts cut by max_rounds
 }
+
+
+def _rng(seed: int, *stream: int) -> np.random.Generator:
+    # any whole number, negative or past 64 bits, maps to one entropy word
+    return np.random.default_rng([seed % (1 << 64), *stream])
+
+
+def burst_sizes(mix: Dict[str, Any], seed: int, ranks: int) -> Iterator[np.ndarray]:
+    """Per-rank seed counts of successive bursts: ``(lo + v) · rows`` with
+    ``v`` in ``[0, spread)``, upstream's ``int(10 + 128·drand48()) · 128``.
+
+    ``v`` is drawn stratified, so that every seed offers the same work in
+    another order: each block of ``spread`` bursts of a rank holds every
+    value once, in antithetic pairs ``(v, spread−1−v)`` whose order the seed
+    shuffles, and ranks ``2k`` and ``2k+1`` take complementary values in
+    every burst.  Any whole number of pairs of bursts then seeds the same
+    rays on every seed."""
+    lo, spread, rows = mix["seed_blocks_min"], mix["seed_blocks_spread"], mix["block_rows"]
+    if spread % 2:
+        raise ValueError("seed_blocks_spread must be even (antithetic pairs)")
+    rngs = [_rng(seed, r) for r in range(0, ranks, 2)]
+
+    def block(rng):
+        pairs = rng.permutation(spread // 2)
+        flip = rng.integers(0, 2, size=spread // 2)
+        first = np.where(flip == 1, spread - 1 - pairs, pairs)
+        return np.stack([first, spread - 1 - first], axis=1).reshape(-1)
+
+    while True:
+        lead = np.stack([block(g) for g in rngs], axis=1)  # (spread, ceil(R/2))
+        vals = np.stack([lead, spread - 1 - lead], axis=2).reshape(spread, -1)[:, :ranks]
+        for v in vals:
+            yield ((lo + v) * rows).astype(np.int32)
+
+
+def jobs(mix: Dict[str, Any], seed: int, ranks: int) -> List[np.ndarray]:
+    """The window's bursts: the first ``staged_jobs`` of :func:`burst_sizes`.
+    Raises ``ValueError`` for a traffic kind other than ``bursts``."""
+    if mix["kind"] != "bursts":
+        raise ValueError(f"traffic kind {mix['kind']!r}: the miniApp draws only 'bursts'")
+    return list(itertools.islice(burst_sizes(mix, seed, ranks), mix["staged_jobs"]))
 
 
 def _mix(src_rank, src_id, rnd, rank, tid):

@@ -40,7 +40,7 @@ def main(argv=None):
     module = harness.load_module(cell.config_module)
     for seed in (int(s) for s in args.seeds.split(",")):
         deploy = module.Deployment(cell.spec, cell.mix, devices)
-        jobs = harness.jobs(cell.mix, seed, cell.chips)
+        jobs = harness.jobs(cell, seed)
         deploy.warm(jobs)
         records, window_s = harness.drive(
             deploy, jobs, args.seconds, unit=cell.mix.get("window_unit_jobs", 1)

@@ -1,12 +1,19 @@
-"""The chip benchmark's cell table, traffic generator and measured window.
+"""The chip benchmark's cell table, job draw and measured window.
 
 Everything here is found by name from ``BENCHMARK.json``:
 
   configs/<config>.json   the deployment's sizes, as run
   configs/<config>.py     how to build it on R ranks, drive one job, and
-                          compare the answers with the plain reference
-  traffic/<mix>.json      parameters that :func:`jobs` turns into the
-                          window's jobs from the seed
+                          compare the answers with the plain reference;
+                          ``SMALL``, the sizes a CPU test replaces; and
+                          ``jobs(mix, seed, ranks)``, which draws the
+                          window's jobs of its traffic kinds from the seed
+  configs/<config>_reference.py   the plain reference
+  configs/<config>_faults.py      ``FAULTS``, the faults planted in the
+                          timed path that the comparison must catch
+  traffic/<mix>.json      parameters that the configuration's ``jobs``
+                          turns into the window's jobs
+  traffic/<mix>.small.json        the parameters a CPU test replaces
   metrics/<metric>.py     ``read(run) -> float | None`` for one metric;
                           ``<base>.<part>`` falls back to ``metrics/<base>.py``,
                           so one reader serves a quantity split by the
@@ -19,13 +26,10 @@ from __future__ import annotations
 
 import dataclasses
 import importlib.util
-import itertools
 import json
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
-
-import numpy as np
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent.parent
@@ -102,47 +106,13 @@ def metric_reader(name: str) -> Callable[["Run"], Optional[float]]:
 
 # ------------------------------------------------------------------ traffic
 
-def _rng(seed: int, *stream: int) -> np.random.Generator:
-    # any whole number, negative or past 64 bits, maps to one entropy word
-    return np.random.default_rng([seed % (1 << 64), *stream])
-
-
-def burst_sizes(mix: Dict[str, Any], seed: int, ranks: int) -> Iterator[np.ndarray]:
-    """Per-rank seed counts of successive bursts: ``(lo + v) · rows`` with
-    ``v`` in ``[0, spread)``, upstream's ``int(10 + 128·drand48()) · 128``.
-
-    ``v`` is drawn stratified, so that every seed offers the same work in
-    another order: each block of ``spread`` bursts of a rank holds every
-    value once, in antithetic pairs ``(v, spread−1−v)`` whose order the seed
-    shuffles, and ranks ``2k`` and ``2k+1`` take complementary values in
-    every burst.  Any whole number of pairs of bursts then seeds the same
-    rays on every seed."""
-    lo, spread, rows = mix["seed_blocks_min"], mix["seed_blocks_spread"], mix["block_rows"]
-    if spread % 2:
-        raise ValueError("seed_blocks_spread must be even (antithetic pairs)")
-    rngs = [_rng(seed, r) for r in range(0, ranks, 2)]
-
-    def block(rng):
-        pairs = rng.permutation(spread // 2)
-        flip = rng.integers(0, 2, size=spread // 2)
-        first = np.where(flip == 1, spread - 1 - pairs, pairs)
-        return np.stack([first, spread - 1 - first], axis=1).reshape(-1)
-
-    while True:
-        lead = np.stack([block(g) for g in rngs], axis=1)  # (spread, ceil(R/2))
-        vals = np.stack([lead, spread - 1 - lead], axis=2).reshape(spread, -1)[:, :ranks]
-        for v in vals:
-            yield ((lo + v) * rows).astype(np.int32)
-
-
-def jobs(mix: Dict[str, Any], seed: int, ranks: int) -> List[Any]:
-    """The jobs a window may run, in order: the first ``staged_jobs`` of the
-    stream a traffic mix describes, drawn from ``seed`` before the window
-    opens, so that a deployment can put them on the device in set-up."""
-    kind = mix["kind"]
-    if kind == "bursts":
-        return list(itertools.islice(burst_sizes(mix, seed, ranks), mix["staged_jobs"]))
-    raise ValueError(f"unknown traffic kind {kind!r}")
+def jobs(cell: Cell, seed: int) -> List[Any]:
+    """The jobs a window of ``cell`` may run, in order, drawn from ``seed``
+    before the window opens, so that a deployment can put them on the
+    device in set-up: ``jobs(mix, seed, ranks)`` of the cell's
+    configuration module."""
+    draw = load_module(cell.config_module).jobs
+    return list(draw(cell.mix, seed, cell.chips))
 
 
 # ------------------------------------------------------------------ window
@@ -159,20 +129,22 @@ class Run:
     traced: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
 
 
-def drive(deploy, stream: Iterable[Any], seconds: float, *, unit: int = 1, on_job=None):
+def drive(deploy, stream: Iterable[Any], seconds: float, *, unit: int = 1, on_job=None,
+          clock: Callable[[], float] = time.perf_counter):
     """Run jobs back to back until ``seconds`` have passed and the jobs run
     are a whole number of ``unit``s (the traffic's ``window_unit_jobs``): the
     jobs in flight at the deadline finish and count.  Raises
     ``RuntimeError`` when ``stream`` ends first.  Each record gets ``t0`` and
     ``t1``, seconds from the window's start to the job's dispatch and to its
     answer on the host.  ``on_job(record)`` runs after each job, outside
-    its timing.  Returns ``(records, window_s)``."""
+    its timing.  ``clock`` gives the time in seconds.  Returns ``(records,
+    window_s)``."""
     records: List[Dict[str, Any]] = []
-    start = time.perf_counter()
+    start = clock()
     for job in stream:
-        t0 = time.perf_counter()
+        t0 = clock()
         rec = deploy.run(job)
-        t1 = time.perf_counter()
+        t1 = clock()
         rec["t0"], rec["t1"] = t0 - start, t1 - start
         records.append(rec)
         if on_job is not None:

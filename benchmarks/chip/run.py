@@ -14,7 +14,8 @@ with ``--trace 1`` its per-layer metrics, from ``metrics/<name>.py``),
 ``device``, with ``--trace 1`` ``breakdown``, and last ``checks``: each
 number compared with its limit.  The checks are also the last lines of
 standard error.  With ``--trace 1`` the profiler records the first
-``TRACE_SECONDS`` of the window (at least one job).
+``TRACE_SECONDS`` of the window (at least one job); its device time is
+split by ``rafi.*`` scope for the readers, once the window has closed.
 """
 from __future__ import annotations
 
@@ -129,7 +130,7 @@ def execute(cell, devices, args, *, on_chip=True):
     module = harness.load_module(cell.config_module)
     deploy = module.Deployment(cell.spec, cell.mix, devices)
     t_build = time.perf_counter()
-    jobs = harness.jobs(cell.mix, args.seed, cell.chips)
+    jobs = harness.jobs(cell, args.seed)
     deploy.warm(jobs)
     setup_s = time.perf_counter() - t0
     _log(f"setup: init_s={t0 - T0:.3f} build_s={t_build - t0:.3f} "
@@ -169,6 +170,10 @@ def execute(cell, devices, args, *, on_chip=True):
             return 3
     if run.trace is not None:
         dev.update(busy_s=run.trace.busy_s, window_s=run.trace.window_s)
+        _log("scopes: " + json.dumps({
+            "busy_s": run.trace.busy_s,
+            "ms": {n: t * 1e3 for n, t in run.trace.scopes.items()},
+        }))
     wanted = cell.per_layer if args.trace else cell.end_to_end
     metrics = {}
     for m in wanted:

@@ -1,5 +1,9 @@
 """Cells of the benchmark cut to a size a CPU test can hold, and a helper that
-drives the rest of a run (everything after the look for a chip)."""
+drives the rest of a run (everything after the look for a chip).
+
+A configuration brings its test sizes as ``SMALL`` in ``configs/<config>.py``
+and a traffic mix as ``traffic/<mix>.small.json``; each replaces those keys
+of the sizes as run."""
 from __future__ import annotations
 
 import argparse
@@ -9,21 +13,18 @@ import json
 
 import harness
 
-# per configuration: sizes of the deployment and of its traffic at test size
-SPEC = {
-    "miniapp_upstream": {"queue_rows_per_mesh_rank": 1024},
-}
-MIX = {
-    "r1": {"seed_blocks_min": 2, "seed_blocks_spread": 8, "block_rows": 16},
-    "r4": {"seed_blocks_min": 2, "seed_blocks_spread": 8, "block_rows": 16},
-}
 CELLS = [w["name"] for w in harness.load_bench()["workloads"]]
+
+
+def small_traffic(traffic):
+    return harness.HERE / "traffic" / f"{traffic}.small.json"
 
 
 def cell(name):
     c = harness.resolve(harness.load_bench(), name)
-    c.spec.update(SPEC[c.config])
-    c.mix.update(MIX[c.traffic])
+    c.spec.update(harness.load_module(c.config_module).SMALL)
+    with open(small_traffic(c.traffic)) as f:
+        c.mix.update(json.load(f))
     return c
 
 

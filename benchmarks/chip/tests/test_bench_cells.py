@@ -1,14 +1,17 @@
 """Every cell of BENCHMARK.json resolves to its files by name, the file keeps
 the shape its format fixes, and the traffic generator draws every seed's
 bursts from one mix."""
+import dataclasses
+import hashlib
 import itertools
+import json
 import re
 
 import numpy as np
 import pytest
 
 import harness
-from small_cells import CELLS
+from small_cells import CELLS, small_traffic
 
 BENCH = harness.load_bench()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -21,7 +24,13 @@ def test_cell_resolves_to_its_files(name):
     cell = harness.resolve(BENCH, name)
     assert cell.config_module.is_file()
     assert cell.config_module.with_name(f"{cell.config}_reference.py").is_file()
-    assert callable(harness.load_module(cell.config_module).Deployment)
+    module = harness.load_module(cell.config_module)
+    assert callable(module.Deployment)
+    assert set(module.SMALL) <= set(cell.spec)
+    with open(small_traffic(cell.traffic)) as f:
+        assert set(json.load(f)) <= set(cell.mix)
+    faults = harness.load_module(cell.config_module.with_name(f"{cell.config}_faults.py"))
+    assert faults.FAULTS and all(callable(plant) for plant in faults.FAULTS.values())
     e2e = [m["name"] for m in cell.end_to_end]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert cell.per_layer
@@ -94,7 +103,7 @@ def test_benchmark_file_keeps_its_shape():
 
 def _bursts(seed, n, traffic="r4"):
     cell = harness.resolve(BENCH, f"miniapp_upstream.{traffic}")
-    return np.stack(list(itertools.islice(harness.jobs(cell.mix, seed, cell.chips), n)))
+    return np.stack(harness.jobs(cell, seed)[:n])
 
 
 @pytest.mark.parametrize("seed", [0, 2**31 + 5, -3, 2**70])
@@ -125,32 +134,87 @@ def test_coupled_ranks_seed_the_same_rays_every_burst():
     assert np.all(a[:, 0] + a[:, 1] == pair) and np.all(a[:, 2] + a[:, 3] == pair)
 
 
+# the first 512 bursts of each traffic at two seeds, as the shared harness
+# drew them before the draw moved into configs/miniapp_upstream.py
+BURSTS_SHA256 = {
+    ("r1", 2**31 + 7): "bbd9915f7c703512efa827d2e14d6092d403a8c7f20eb784f9e1d923a8107a8f",
+    ("r1", 1414213562): "8a13ba3f9cb1abd9902d517a21390f153012271a23a85d990eb799e320c7e738",
+    ("r4", 2**31 + 7): "61f98964609d718d9f00bf654731cd5d6c4b6445d27256d91d048c310a2d1b96",
+    ("r4", 1414213562): "8d42320806a916973a8b35bfa4618790b8a8f91244263d237a004e60cc3271af",
+}
+
+
+@pytest.mark.parametrize("traffic,seed", list(BURSTS_SHA256))
+def test_bursts_are_unchanged(traffic, seed):
+    a = _bursts(seed, 512, traffic)
+    assert a.dtype == np.int32 and a.shape == (512, 1 if traffic == "r1" else 4)
+    assert hashlib.sha256(a.tobytes()).hexdigest() == BURSTS_SHA256[traffic, seed]
+
+
+def _digest(jobs):
+    return hashlib.sha256(json.dumps(
+        jobs, default=lambda a: np.asarray(a).tolist(), sort_keys=True).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_every_cell_draws_its_jobs_from_the_seed(name):
+    """Whatever its traffic kind, a cell's jobs come from the seed alone:
+    the same seed draws the same jobs, another seed others, and a window
+    can end on a whole unit of them."""
+    cell = harness.resolve(BENCH, name)
+    jobs = harness.jobs(cell, 2**33 + 3)
+    assert len(jobs) >= cell.mix.get("window_unit_jobs", 1)
+    assert _digest(jobs) == _digest(harness.jobs(cell, 2**33 + 3))
+    assert _digest(jobs) != _digest(harness.jobs(cell, 2**33 + 4))
+
+
+def test_a_kind_no_one_draws_is_refused():
+    cell = harness.resolve(BENCH, "miniapp_upstream.r1")
+    cell = dataclasses.replace(cell, mix=dict(cell.mix, kind="no_such_kind"))
+    with pytest.raises(ValueError, match="no_such_kind"):
+        harness.jobs(cell, 1)
+
+
+class _FakeClock:
+    """Seconds that pass only when a job runs."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+
 class _Clock:
-    """A deployment whose jobs take 0.01 s each."""
+    """A deployment whose jobs take 0.01 s each on its clock."""
+
+    def __init__(self):
+        self.clock = _FakeClock()
 
     def run(self, job):
-        import time
-
-        time.sleep(0.01)
+        self.clock.now += 0.01
         return {"job": job}
 
 
 def test_window_that_outruns_its_jobs_is_refused():
+    deploy = _Clock()
     with pytest.raises(RuntimeError, match="ran out"):
-        harness.drive(_Clock(), range(3), 1.0)
+        harness.drive(deploy, range(3), 1.0, clock=deploy.clock)
 
 
 @pytest.mark.parametrize("traffic", ["r1", "r4"])
 def test_jobs_are_drawn_before_the_window(traffic):
     cell = harness.resolve(BENCH, f"miniapp_upstream.{traffic}")
-    jobs = harness.jobs(cell.mix, 7, cell.chips)
+    jobs = harness.jobs(cell, 7)
     assert isinstance(jobs, list) and len(jobs) == cell.mix["staged_jobs"]
     assert len(jobs) % cell.mix["window_unit_jobs"] == 0
 
 
 @pytest.mark.parametrize("unit", [1, 2, 3])
 def test_window_ends_on_a_whole_unit(unit):
-    records, window_s = harness.drive(_Clock(), itertools.count(), 0.025, unit=unit)
+    deploy = _Clock()
+    records, window_s = harness.drive(deploy, itertools.count(), 0.025, unit=unit,
+                                      clock=deploy.clock)
     assert len(records) % unit == 0 and len(records) >= 3
     assert window_s >= 0.025 and window_s == records[-1]["t1"]
     assert [r["job"] for r in records] == list(range(len(records)))

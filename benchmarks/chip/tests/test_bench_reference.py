@@ -10,7 +10,7 @@ MINIAPP = ["miniapp_upstream.r1", "miniapp_upstream.r4"]
 
 def _window(name, n):
     cell, d = deployment(name)
-    jobs = harness.jobs(cell.mix, 2**31 + 11, cell.chips)
+    jobs = harness.jobs(cell, 2**31 + 11)
     d.warm(jobs)
     return cell, d, [d.run(job) for job in jobs[:n]]
 

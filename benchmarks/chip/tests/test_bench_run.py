@@ -1,15 +1,12 @@
 """Whole runs of every cell at a small size on the CPU: the result line, the
 refusal without a chip or without the program, and ``correct`` coming out
 false when the timed path is broken underneath."""
-import dataclasses
 import json
 import os
 import shutil
 import subprocess
 import sys
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 import harness
@@ -67,71 +64,23 @@ def test_small_run_prints_its_result(name, trace):
 
 
 # ---------------------------------------------------------------- faults
-# Each breaks the timed path underneath the harness, in the program's own
-# drive (repro.core.termination), the way a faulty change could.
+# Each configuration lists in configs/<config>_faults.py the faults planted
+# in its timed path, underneath the harness, the way a faulty change could.
 
-def _unchanged(monkeypatch, T):
-    """A round that returns its state unchanged."""
-    orig = T.drive_segment
-
-    def segment(round_fn, carry, cfg, **kw):
-        return orig(lambda q, aux, rnd, **_: (q, aux), carry, cfg, **kw)
-
-    monkeypatch.setattr(T, "drive_segment", segment)
+def _faults(name):
+    cell = harness.resolve(harness.load_bench(), name)
+    mod = harness.load_module(cell.config_module.with_name(f"{cell.config}_faults.py"))
+    across = getattr(mod, "ACROSS_CHIPS", set())
+    return {f: plant for f, plant in mod.FAULTS.items() if f not in across or cell.chips > 1}
 
 
-def _half(monkeypatch, T):
-    """Half of every round's rows left out of the forward."""
-    orig = T.forward_work
-
-    def forward(q, cfg, **kw):
-        lane = jnp.arange(q.dest.shape[0])
-        dest = jnp.where(lane >= (q.count + 1) // 2, -1, q.dest)
-        return orig(dataclasses.replace(q, dest=dest), cfg, **kw)
-
-    monkeypatch.setattr(T, "forward_work", forward)
-
-
-def _no_exchange(monkeypatch, T):
-    """The exchange between chips left out: every row stays on its rank."""
-    orig = T.forward_work
-
-    def forward(q, cfg, **kw):
-        me = jax.lax.axis_index(cfg.axis_name)
-        return orig(dataclasses.replace(q, dest=jnp.where(q.dest >= 0, me, q.dest)), cfg, **kw)
-
-    monkeypatch.setattr(T, "forward_work", forward)
-
-
-def _altered(monkeypatch, T):
-    """A few delivered items altered where the forward produces them."""
-    orig = T.forward_work
-
-    def forward(q, cfg, **kw):
-        out = orig(q, cfg, **kw)
-        lane = jnp.arange(out[0].dest.shape[0])
-
-        def alter(a):
-            sel = (lane < 8).reshape((-1,) + (1,) * (a.ndim - 1))
-            return jnp.where(sel, a + 1, a).astype(a.dtype)
-
-        new_q = dataclasses.replace(out[0], items=jax.tree.map(alter, out[0].items))
-        return (new_q,) + tuple(out[1:])
-
-    monkeypatch.setattr(T, "forward_work", forward)
-
-
-FAULTS = {"unchanged": _unchanged, "half": _half, "no_exchange": _no_exchange,
-          "altered": _altered}
-CASES = [(c, f) for c in CELLS for f in FAULTS
-         if f != "no_exchange" or harness.resolve(harness.load_bench(), c).chips > 1]
+FAULTS = {c: _faults(c) for c in CELLS}
+CASES = [(c, f) for c in CELLS for f in FAULTS[c]]
 
 
 @pytest.mark.parametrize("name,fault", CASES)
 def test_broken_timed_path_is_not_correct(name, fault, monkeypatch):
-    from repro.core import termination
-
-    FAULTS[fault](monkeypatch, termination)
+    FAULTS[name][fault](monkeypatch)
     res = run(name)
     assert res["correct"] is False
     assert res["failed"] >= 1
