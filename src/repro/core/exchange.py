@@ -86,8 +86,9 @@ bound mesh axis:
   ``.lower()``-validated; on JAX builds without the op it raises.
 * ``padded`` — fixed per-peer slots of size ``peer_capacity`` exchanged with
   a single tiled ``all_to_all`` of the packed buffer.  Portable (runs on
-  CPU; used by the dry-run compile) at the cost of padding bandwidth.  This
-  is also the natural MoE-dispatch form (capacity-factor semantics).
+  CPU; used by the dry-run compile) at the cost of padding bandwidth.  The
+  MoE dispatch runs on it: a peer's slots bound a round's tokens for it,
+  and under ``overflow="retain"`` the rest wait for the next round.
 * ``hierarchical`` — the N-stage padded exchange over an N-D ``(slowest, …,
   fastest)`` mesh described above: per-tier combine from the fastest axis
   inward, one collective per axis.  Placement is bit-identical to the flat

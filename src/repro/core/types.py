@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import LANES
+
 __all__ = [
     "work_item",
     "item_nbytes",
@@ -99,10 +101,12 @@ def tree_scatter(buf, pos, vals, *, capacity: int):
 # needs exactly one payload gather and one payload collective instead of one
 # per pytree leaf.
 #
-# Layout: leaves in treedef order, each flattened to its per-item byte string
+# Layout: leaves in treedef order, each flattened to its per-item elements
 # and bitcast to ≥1 whole uint32 words (sub-word dtypes are zero-padded up to
-# a word boundary; the pad words travel but carry no information and are
-# stripped on unpack).  Pack ∘ unpack is the identity bit-for-bit.
+# a word boundary; the pad travels but carries no information and is
+# stripped on unpack).  A 2-byte leaf of n elements puts elements j and
+# j + n/2 into word j; a 1-byte leaf puts 4 neighbours into each word.
+# Pack ∘ unpack is the identity bit-for-bit.
 # --------------------------------------------------------------------------
 
 
@@ -115,6 +119,12 @@ class PackSpec:
       shapes: per-leaf trailing (per-item) shapes.
       dtypes: per-leaf dtype names.
       words: per-leaf packed word counts (incl. sub-word padding).
+
+    A row wider than one lane tile (``LANES`` words) is zero-padded to a
+    whole number of tiles: the TPU lays a payload's rows out in (8, 128)
+    tiles anyway, and its all-to-all moves a row of whole tiles in place,
+    where a ragged width makes it transpose the whole buffer before the
+    collective and back after.
     """
 
     treedef: Any
@@ -123,8 +133,13 @@ class PackSpec:
     words: tuple
 
     @property
-    def total_words(self) -> int:
+    def item_words(self) -> int:
         return sum(self.words)
+
+    @property
+    def total_words(self) -> int:
+        w = self.item_words
+        return w if w <= LANES else -(-w // LANES) * LANES
 
     @property
     def offsets(self) -> tuple:
@@ -166,8 +181,21 @@ def _leaf_to_words(a: jax.Array) -> jax.Array:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32)
     if b == 8:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32).reshape(cap, -1)
-    # sub-word (1- or 2-byte) dtypes: zero-pad the minor axis to a whole
-    # number of words, then bitcast groups of 4//b elements into each word
+    if b == 2:
+        # 2-byte dtypes: word j holds element j in its low half and element
+        # j + n/2 in its high half (minor axis zero-padded to even).  Halves
+        # rather than neighbours keep the packing elementwise over the
+        # minor axis: a bitcast of interleaved pairs lays a size-2 axis
+        # along the TPU's lanes and materialises every element as a word
+        flat = jax.lax.bitcast_convert_type(flat, jnp.uint16)
+        if flat.shape[1] % 2:
+            flat = jnp.pad(flat, ((0, 0), (0, 1)))
+        half = flat.shape[1] // 2
+        lo = flat[:, :half].astype(jnp.uint32)
+        hi = flat[:, half:].astype(jnp.uint32)
+        return lo | (hi << 16)
+    # 1-byte dtypes: zero-pad the minor axis to a whole number of words,
+    # then bitcast groups of 4 elements into each word
     per = 4 // b
     pad = (-flat.shape[1]) % per
     if pad:
@@ -186,6 +214,9 @@ def _words_to_leaf(seg: jax.Array, shape, dtype) -> jax.Array:
     b = np.dtype(wire_dt).itemsize
     if b == 4:
         out = jax.lax.bitcast_convert_type(seg, wire_dt)[:, :n]
+    elif b == 2:
+        halves = [(seg & 0xFFFF).astype(jnp.uint16), (seg >> 16).astype(jnp.uint16)]
+        out = jax.lax.bitcast_convert_type(jnp.concatenate(halves, axis=1), wire_dt)[:, :n]
     elif b == 8:
         out = jax.lax.bitcast_convert_type(seg.reshape(cap, -1, 2), wire_dt)[:, :n]
     else:
@@ -202,6 +233,9 @@ def pack_payload(items: Any, spec: PackSpec | None = None):
     if spec is None:
         spec = pack_spec(jax.tree.map(lambda a: a[0], items))
     cols = [_leaf_to_words(l) for l in jax.tree.leaves(items)]
+    pad = spec.total_words - spec.item_words
+    if pad:
+        cols.append(jnp.zeros((cols[0].shape[0], pad), jnp.uint32))
     packed = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
     return packed, spec
 

@@ -14,6 +14,7 @@ produced from the *same* path rules used by both init and the dry-run, so
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -164,10 +165,16 @@ def dense(x, w, b=None):
     return y
 
 
-def glu_mlp(x, wi, wg, wo, act: str):
-    """Gated MLP (SwiGLU/GeGLU): down( act(gate(x)) * up(x) )."""
-    a = jax.nn.silu(x @ wg) if act == "silu" else jax.nn.gelu(x @ wg)
-    return (a * (x @ wi)) @ wo
+def glu_mlp(x, wi, wg, wo, act: str, acc=None):
+    """Gated MLP (SwiGLU/GeGLU): down( act(gate(x)) * up(x) ).  With ``acc``
+    (e.g. float32) every product accumulates in that dtype, the hidden is
+    cast back to ``x``'s dtype for the down product, and the result is in
+    ``acc``."""
+    mm = functools.partial(jnp.matmul, preferred_element_type=acc)
+    g = mm(x, wg)
+    a = jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)
+    h = a * mm(x, wi)
+    return mm(h if acc is None else h.astype(x.dtype), wo)
 
 
 def mlp_defs(cfg: ModelConfig, d_ff: Optional[int] = None) -> Dict[str, ParamDef]:

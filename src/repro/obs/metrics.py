@@ -27,7 +27,10 @@ checkpoint manifest.
 :data:`REGISTRY` holds what the program itself declares while it is traced:
 ``rafi_payload_rows_per_forward``, the rows one rank's payload pass moves
 per forward, summed over the exchange's stages (``core.stages.Marshal``
-sets it as it builds each send buffer; the last program traced wins).  This
+sets it as it builds each send buffer; the last program traced wins), and
+``rafi_moe_rows_per_layer``, the rows one rank's grouped expert matmul is
+given per MoE layer (``models.moe.ep_moe_layer``; the assignments it
+processes are counted per job).  This
 module imports nothing from ``repro.core``, so the core can record here.
 """
 from __future__ import annotations
@@ -41,6 +44,7 @@ import numpy as np
 from repro.telemetry import stats as TS
 
 __all__ = [
+    "MOE_ROWS",
     "PAYLOAD_ROWS",
     "REGISTRY",
     "Metric",
@@ -104,6 +108,7 @@ class Registry:
 
 REGISTRY = Registry()
 PAYLOAD_ROWS = "rafi_payload_rows_per_forward"
+MOE_ROWS = "rafi_moe_rows_per_layer"
 
 
 def from_summary(summary: Dict[str, Any], *, prefix: str = "rafi") -> List[Metric]:

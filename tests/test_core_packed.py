@@ -22,6 +22,7 @@ from hypothesis import given, settings, strategies as st
 from repro import compat
 from repro.core import ForwardConfig, enqueue, forward_work, make_queue, work_item
 from repro.core import types as T
+from repro.kernels import LANES
 
 from helpers import Ray, make_rays, ray_proto
 
@@ -70,6 +71,25 @@ def test_pack_unpack_identity_subword_and_bool():
     for k in items:
         assert back[k].dtype == items[k].dtype
         np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(items[k]))
+
+
+@pytest.mark.parametrize("width", [300, 301, 7167, 7168])
+def test_pack_unpack_wide_bf16_rows_pad_to_lane_tiles(width):
+    """A bfloat16 row wider than one lane tile packs two elements a word, is
+    zero-padded to whole ``LANES``-word tiles, and round-trips bit for bit
+    (every 16-bit pattern, NaNs included)."""
+    n = 5
+    bits = np.random.default_rng(width).integers(0, 2**16, (n, width), dtype=np.uint16)
+    items = {"h": jnp.asarray(bits.view(jnp.bfloat16)), "slot": jnp.arange(n, dtype=jnp.int32)}
+    packed, spec = T.pack_payload(items)
+    assert spec.item_words == -(-width // 2) + 1
+    assert packed.shape == (n, spec.total_words)
+    assert spec.total_words % LANES == 0 and spec.total_words - spec.item_words < LANES
+    np.testing.assert_array_equal(np.asarray(packed[:, spec.item_words:]), 0)
+    back = T.unpack_payload(packed, spec)
+    assert back["h"].dtype == jnp.bfloat16 and back["h"].shape == (n, width)
+    np.testing.assert_array_equal(np.asarray(back["h"]).view(np.uint16), bits)
+    np.testing.assert_array_equal(np.asarray(back["slot"]), np.arange(n))
 
 
 def test_pack_unpack_zero_size_leaf():
@@ -218,3 +238,56 @@ def test_packed_forward_multi_leaf_dtypes(mesh8):
                 np.arange(k * 6, k * 6 + 6, dtype=np.float32).reshape(2, 3)
                 + src * 1000,
             )
+
+
+@pytest.mark.parametrize("width", [61, 301, 7168])
+def test_packed_forward_wide_bf16_rows(mesh8, width):
+    """Rows wider than one lane tile, zero-padded to whole tiles on the
+    wire, cross the padded exchange bit for bit, as a row of one tile
+    (61 elements) does."""
+
+    @work_item
+    @dataclasses.dataclass
+    class Wide:
+        h: jax.Array    # (width,) bf16
+        tag: jax.Array  # () i32
+
+    def proto():
+        return Wide(h=jnp.zeros((width,), jnp.bfloat16), tag=jnp.zeros((), jnp.int32))
+
+    words = T.pack_spec(proto()).total_words
+    assert words % LANES == 0 if width > 2 * LANES else words == -(-width // 2) + 1
+    cfg = ForwardConfig("data", R, CAP, exchange="padded")
+
+    def pattern(tag):
+        # finite bfloat16 bit patterns (a NaN's payload is not kept through
+        # the queue's float selects, at any width)
+        lane = jnp.arange(width, dtype=jnp.int32)
+        return ((lane[None] * 7 + tag[:, None] * 131) % 0x7F00).astype(jnp.uint16)
+
+    def kernel(_x):
+        q = make_queue(proto(), CAP)
+        me = jax.lax.axis_index("data")
+        n = 6
+        tag = (jnp.arange(n) + me * 100).astype(jnp.int32)
+        items = Wide(h=jax.lax.bitcast_convert_type(pattern(tag), jnp.bfloat16), tag=tag)
+        dest = ((me + jnp.arange(n)) % R).astype(jnp.int32)
+        q = enqueue(q, items, dest, jnp.ones(n, bool))
+        nq, total = forward_work(q, cfg)
+        return nq.count[None], nq.items.tag, jax.lax.bitcast_convert_type(nq.items.h, jnp.uint16), total
+
+    f = jax.jit(
+        jax.shard_map(
+            kernel, mesh=mesh8, in_specs=P("data"),
+            out_specs=(P("data"), P("data"), P("data"), P()),
+        )
+    )
+    counts, tags, hs, total = f(jnp.arange(8.0))
+    counts = np.asarray(counts)
+    tags = np.asarray(tags).reshape(R, CAP)
+    hs = np.asarray(hs).reshape(R, CAP, width)
+    assert int(total) == 8 * 6 and counts.sum() == 48
+    for r in range(R):
+        got = tags[r, : counts[r]]
+        assert all((t // 100 + t % 100) % R == r for t in got)  # addressed here
+        np.testing.assert_array_equal(hs[r, : counts[r]], np.asarray(pattern(jnp.asarray(got))))
