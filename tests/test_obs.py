@@ -538,7 +538,8 @@ def test_round_ops_carry_rafi_scopes(backend, mesh8, mesh_pods222):
                 or (operands and set(operands) <= consts)
             ):
                 continue
-            if "rafi.drive" not in line:
+            # the seeding enqueue's own append loop runs before the drive
+            if "rafi.drive" not in line and "rafi.enqueue/while" not in line:
                 unscoped.append(line)
     assert not unscoped, unscoped[:5]
     found = set(re.findall(r"rafi\.([a-z_]+)", hlo))

@@ -166,3 +166,26 @@ def test_pallas_forward_compiles_on_four_chips(topo, monkeypatch, marshal):
     text = _compiled_text(f, spec)
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
+
+
+def test_enqueue_appends_in_place_at_prefill_widths(one_chip):
+    """One ``enqueue`` of a DeepSeek-V3 prefill rank: 32,768 lanes of 14 KB
+    token rows (hidden 7,168 bf16) into a 49,152-row queue.  The append
+    writes the queue in place: the program holds less than one copy of the
+    queue in temporaries, and no scatter."""
+    from repro.core import enqueue, make_queue
+    from repro.models.moe import _token_proto
+
+    cap, lanes, d = 49152, 32768, 7168
+    proto = _token_proto(d, jnp.bfloat16)
+    q = jax.eval_shape(lambda: make_queue(proto, cap))
+    items = jax.tree.map(lambda l: jax.ShapeDtypeStruct((lanes,) + l.shape, l.dtype), proto)
+    args = jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, one_chip),
+        (q, items, jax.ShapeDtypeStruct((lanes,), jnp.int32),
+         jax.ShapeDtypeStruct((lanes,), jnp.bool_)),
+    )
+    compiled = jax.jit(enqueue, donate_argnums=0).lower(*args).compile()
+    queue_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(q))
+    assert compiled.memory_analysis().temp_size_in_bytes < queue_bytes
+    assert "scatter" not in compiled.as_text()
